@@ -187,10 +187,10 @@ _CACHE_AXES = {
     "v": ("batch", "kv_seq", "kv_heads", None),
     "k_scale": ("batch", "kv_seq", "kv_heads", None),
     "v_scale": ("batch", "kv_seq", "kv_heads", None),
-    "kp": (None, None, "kv_heads", None),
-    "vp": (None, None, "kv_heads", None),
-    "kp_scale": (None, None, "kv_heads", None),
-    "vp_scale": (None, None, "kv_heads", None),
+    "kp": (None, "kv_heads", None, None),
+    "vp": (None, "kv_heads", None, None),
+    "kp_scale": (None, "kv_heads", None, None),
+    "vp_scale": (None, "kv_heads", None, None),
     "c_kv": ("batch", "kv_seq", None),
     "k_rope": ("batch", "kv_seq", None),
     "ssm": ("batch", "act_heads", None, None),

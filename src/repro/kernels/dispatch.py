@@ -181,6 +181,29 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     return jax.default_backend() not in ("tpu", "gpu")
 
 
+def _on_mesh(fn, *args, in_specs=None, out_specs=None):
+    """Call a Pallas kernel wrapper ``fn(*args)`` under the active mesh.
+
+    XLA cannot partition a Mosaic kernel, so on a multi-device mesh the
+    call runs inside ``shard_map``: operands enter with ``in_specs``
+    (default: replicated, so every device computes the whole op exactly
+    as one device would) and leave with ``out_specs`` (default:
+    replicated). Without a mesh, or on a one-device mesh, ``fn`` is called
+    directly.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import current_mesh
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=in_specs if in_specs is not None else (P(),) * len(args),
+        out_specs=out_specs if out_specs is not None else P(),
+        check_vma=False)(*args)
+
+
 # ---------------------------------------------------------------------------
 # dispatched operations
 
@@ -205,10 +228,24 @@ def qmatmul(pa: jax.Array, pb: jax.Array, fmt: LNSFormat,
 def _qmatmul(pa, pb, fmt, scale_a=None, scale_b=None, *,
              compute_dtype=jnp.bfloat16, backend=None, interpret=None):
     if resolve_backend(backend) == "pallas":
+        from jax.sharding import PartitionSpec as P
+
+        from repro.distributed.sharding import model_axis_size
         from repro.kernels.ops import lns_qmatmul
-        return lns_qmatmul(pa, pb, fmt, scale_a, scale_b,
-                           compute_dtype=compute_dtype,
-                           interpret=resolve_interpret(interpret))
+        interp = resolve_interpret(interpret)
+        # column-parallel where the mesh's model axis divides N: each device
+        # owns N/m output columns; the kernel's K tiling is fixed, so every
+        # column is bit-identical to the one-device result
+        m = model_axis_size()
+        col = "model" if m > 1 and pb.shape[1] % m == 0 else None
+        per_col = getattr(scale_b, "ndim", 0) == 2 and scale_b.shape[1] > 1
+        return _on_mesh(
+            lambda a, b, sa, sb: lns_qmatmul(a, b, fmt, sa, sb,
+                                             compute_dtype=compute_dtype,
+                                             interpret=interp),
+            pa, pb, scale_a, scale_b,
+            in_specs=(P(), P(None, col), P(), P(None, col) if per_col else P()),
+            out_specs=P(None, col))
     a = lns_decode_packed(pa, fmt, compute_dtype)
     b = lns_decode_packed(pb, fmt, compute_dtype)
     out = jnp.dot(a, b, preferred_element_type=jnp.float32)
@@ -239,8 +276,9 @@ def encode_pack(x: jax.Array, fmt: LNSFormat, scale_axis: Optional[int] = None,
 def _encode_pack(x, fmt, scale_axis=None, *, backend=None, interpret=None):
     if resolve_backend(backend) == "pallas":
         from repro.kernels.ops import quantize_pack
-        return quantize_pack(x, fmt, scale_axis,
-                             interpret=resolve_interpret(interpret))
+        interp = resolve_interpret(interpret)
+        return _on_mesh(lambda x: quantize_pack(x, fmt, scale_axis,
+                                                interpret=interp), x)
     R = x.shape[0]
     scale = compute_scale(x, axis=scale_axis)
     srow = jnp.broadcast_to(
@@ -272,8 +310,9 @@ def requant_pack(packed: jax.Array, src: LNSFormat, dst: LNSFormat, *,
 def _requant_pack(packed, src, dst, *, backend=None, interpret=None):
     if resolve_backend(backend) == "pallas":
         from repro.kernels.ops import requant_pack as requant_pack_op
-        return requant_pack_op(packed, src, dst,
-                               interpret=resolve_interpret(interpret))
+        interp = resolve_interpret(interpret)
+        return _on_mesh(lambda p: requant_pack_op(p, src, dst,
+                                                  interpret=interp), packed)
     return lns_requant_packed(packed, src, dst)
 
 
@@ -326,14 +365,17 @@ def _madam_step(packed, g, v, count, fmt, *, lr, beta=0.999, eps=1e-30,
     vec = None
     if resolve_backend(backend) == "pallas":
         from repro.kernels.ops import madam_step_packed, madam_step_packed_stats
+        interp = resolve_interpret(interpret)
         if with_stats:
-            np_, nv, vec = madam_step_packed_stats(
-                p2, g2, v2, count, fmt, lr=lr, beta=beta, eps=eps,
-                requant=requant, interpret=resolve_interpret(interpret))
+            np_, nv, vec = _on_mesh(
+                lambda p, g, v, c: madam_step_packed_stats(
+                    p, g, v, c, fmt, lr=lr, beta=beta, eps=eps,
+                    requant=requant, interpret=interp), p2, g2, v2, count)
         else:
-            np_, nv = madam_step_packed(
-                p2, g2, v2, count, fmt, lr=lr, beta=beta, eps=eps,
-                interpret=resolve_interpret(interpret))
+            np_, nv = _on_mesh(
+                lambda p, g, v, c: madam_step_packed(
+                    p, g, v, c, fmt, lr=lr, beta=beta, eps=eps,
+                    interpret=interp), p2, g2, v2, count)
     elif with_stats:
         np_, nv, vec = _madam_step_reference(p2, g2, v2, count, fmt, lr=lr,
                                              beta=beta, eps=eps,
@@ -357,8 +399,8 @@ def paged_attend(q: jax.Array, kp: jax.Array, vp: jax.Array,
                  interpret: Optional[bool] = None) -> jax.Array:
     """Attend ``q`` over a block-paged KV pool through a block table.
 
-    ``q`` is (B, S, H, hd); ``kp``/``vp`` are (P, page, KV, hd) pools —
-    packed LNS words when ``fmt`` is given (with (P, page, KV, 1) scales),
+    ``q`` is (B, S, H, hd); ``kp``/``vp`` are (P, KV, page, hd) pools —
+    packed LNS words when ``fmt`` is given (with (P, KV, page, 1) scales),
     the compute dtype otherwise. ``block_table`` (B, max_pages) maps each
     slot's local page j to a pool page; ``lengths`` (B,) counts the valid
     positions per slot *including* the S just written, so query s sits at
@@ -405,44 +447,29 @@ def _paged_attend(q, kp, vp, k_scale, v_scale, block_table, lengths, *,
         return _paged_attend_reference(q, kp, vp, ks, vs, bt, ln, fmt=fmt,
                                        softcap=softcap, sm_scale=sm_scale)
 
-    from repro.distributed.sharding import current_mesh, model_axis_size
-    mesh = current_mesh()
-    m = model_axis_size(mesh)
-    if mesh is not None and m > 1 and kp.shape[2] % m == 0:
-        return _paged_attend_sharded(impl, mesh, q, kp, vp, k_scale,
-                                     v_scale, block_table, lengths)
-    return impl(q, kp, vp, k_scale, v_scale, block_table, lengths)
+    from repro.distributed.sharding import model_axis_size
+    args = (q, kp, vp, k_scale, v_scale, block_table, lengths)
+    m = model_axis_size()
+    if m > 1 and kp.shape[1] % m == 0:
+        # head-group parallel over the mesh ``model`` axis: each shard
+        # attends its local KV head group (and the matching query group —
+        # GQA groups are contiguous in the head axis, so an even split
+        # never severs one) against its slice of every pool page; the
+        # replicated block table and lengths give every shard the same
+        # page-local view of the one logical table. Heads never mix across
+        # shards, so each shard's result is bitwise what one device
+        # computes for those heads; the trailing constraint is the
+        # explicit all-gather epilogue back to replicated heads.
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
-
-def _paged_attend_sharded(impl, mesh, q, kp, vp, k_scale, v_scale,
-                          block_table, lengths):
-    """Head-group-parallel paged attention over the mesh ``model`` axis.
-
-    Each shard attends its local KV head group (and the matching query
-    group — GQA groups are contiguous in the head axis, so an even head
-    split never severs a group) against its local slice of every pool
-    page; the block table and lengths are replicated, giving every shard
-    the same page-local view of the one logical table. Heads never mix
-    across shards inside attention, so per-shard results are bitwise what
-    a single device computes for those heads; the trailing constraint is
-    the explicit all-gather epilogue back to replicated heads.
-    """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    heads = P(None, None, "model", None)
-    if k_scale is not None:
-        body = lambda q, kp, vp, ks, vs, bt, ln: impl(q, kp, vp, ks, vs,
-                                                      bt, ln)
-        in_specs = (heads, heads, heads, heads, heads, P(None, None), P(None))
-        args = (q, kp, vp, k_scale, v_scale, block_table, lengths)
-    else:
-        body = lambda q, kp, vp, bt, ln: impl(q, kp, vp, None, None, bt, ln)
-        in_specs = (heads, heads, heads, P(None, None), P(None))
-        args = (q, kp, vp, block_table, lengths)
-    out = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=heads,
-                    check_rep=False)(*args)
-    return jax.lax.with_sharding_constraint(out, NamedSharding(mesh, P()))
+        from repro.distributed.sharding import current_mesh
+        heads = P(None, None, "model", None)        # q / out: (B, S, h, hd)
+        pool = P(None, "model", None, None)         # (P, kv, page, ·)
+        out = _on_mesh(impl, *args, out_specs=heads,
+                       in_specs=(heads, pool, pool, pool, pool, P(), P()))
+        return jax.lax.with_sharding_constraint(
+            out, NamedSharding(current_mesh(), P()))
+    return _on_mesh(impl, *args) if use_pallas else impl(*args)
 
 
 def fused_sample(logits: jax.Array, gumbel: Optional[jax.Array],
@@ -471,8 +498,10 @@ def fused_sample(logits: jax.Array, gumbel: Optional[jax.Array],
 def _fused_sample(logits, gumbel, temp, *, backend=None, interpret=None):
     if resolve_backend(backend) == "pallas":
         from repro.kernels.ops import fused_sample as fused_sample_op
-        return fused_sample_op(logits, gumbel, temp,
-                               interpret=resolve_interpret(interpret))
+        interp = resolve_interpret(interpret)
+        return _on_mesh(lambda lg, gm, t: fused_sample_op(lg, gm, t,
+                                                          interpret=interp),
+                        logits, gumbel, temp)
     return _fused_sample_reference(logits, gumbel, temp)
 
 
@@ -498,15 +527,19 @@ def _paged_attend_reference(q, kp, vp, k_scale, v_scale, block_table,
     — head ``h`` maps to group ``h // rep``, matching repeat semantics.
     """
     B, S, h, hd = q.shape
-    page, kv = kp.shape[1], kp.shape[2]
+    kv, page = kp.shape[1], kp.shape[2]
     mp = block_table.shape[1]
     cap = mp * page
 
+    def gather(pool):  # (B, mp, kv, page, ·) -> (B, cap, kv, ·)
+        x = pool[block_table].transpose(0, 1, 3, 2, 4)
+        return x.reshape(B, cap, kv, x.shape[-1])
+
     def view(pool, scale):
-        x = pool[block_table].reshape(B, cap, kv, hd)
+        x = gather(pool)
         if fmt is None:
             return x.astype(jnp.float32)
-        s = scale[block_table].reshape(B, cap, kv, 1)
+        s = gather(scale)
         return lns_decode_packed(x, fmt, jnp.float32) * s.astype(jnp.float32)
 
     rep = h // kv

@@ -25,10 +25,12 @@ def _kernel(x_ref, s_ref, out_ref, *, bits: int, gamma: int):
     x = x_ref[...].astype(jnp.float32)
     s = s_ref[...].astype(jnp.float32)  # (block_r, 1), broadcasts over cols
     max_code = (1 << (bits - 1)) - 1
-    neg = (x < 0).astype(jnp.uint32)
+    # int32 throughout: Mosaic has no f32 -> uint32 cast, and the word
+    # (< 2^bits <= 256) is the same bit pattern in either
+    neg = (x < 0).astype(jnp.int32)
     mag = jnp.abs(x) / s
     e = -jnp.log2(jnp.maximum(mag, jnp.finfo(jnp.float32).tiny)) * gamma
-    e = jnp.clip(jnp.floor(e + 0.5), 0, max_code).astype(jnp.uint32)
+    e = jnp.clip(jnp.floor(e + 0.5), 0, max_code).astype(jnp.int32)
     out_ref[...] = ((neg << (bits - 1)) | e).astype(jnp.uint8)
 
 

@@ -40,6 +40,9 @@ __all__ = ["madam_update_pallas", "madam_update_packed_pallas",
 MADAM_STAT_KEYS = ("sat_lo", "sat_hi", "dead_frac", "qerr_rel",
                    "qerr_gap_ratio", "code_mean", "requant_sat_hi")
 MADAM_STAT_WIDTH = 8
+# the stats kernel's per-tile output block: the smallest f32 tile Mosaic
+# accepts, holding the width-8 vector in row 0
+_STAT_TILE = (8, 128)
 
 
 def requant_spec(src: LNSFormat, dst: Optional[LNSFormat]):
@@ -56,9 +59,10 @@ def requant_spec(src: LNSFormat, dst: Optional[LNSFormat]):
     return (src.gamma // dst.gamma, dst.max_code)
 
 
-def madam_stats_vec(code, target, new_code, *, gamma: int, max_code: int,
-                    requant=None):
-    """Partial-sum stat vector over one tile (or one whole leaf).
+def madam_stats_terms(code, target, new_code, *, gamma: int, max_code: int,
+                      requant=None):
+    """The ``MADAM_STAT_WIDTH`` partial sums over one tile (or one whole
+    leaf), as f32 scalars in the slot order above.
 
     Pure elementwise jnp + full reductions, so the same function traces
     inside the Pallas kernel body and in the jnp reference backend —
@@ -80,7 +84,15 @@ def madam_stats_vec(code, target, new_code, *, gamma: int, max_code: int,
         req_hi = jnp.sum(f32((nc + ratio // 2) // ratio > dst_max))
     else:
         req_hi = zero
-    return jnp.stack([n_lo, n_hi, dead, qerr, code_sum, req_hi, zero, zero])
+    return [n_lo, n_hi, dead, qerr, code_sum, req_hi, zero, zero]
+
+
+def madam_stats_vec(code, target, new_code, *, gamma: int, max_code: int,
+                    requant=None):
+    """:func:`madam_stats_terms` stacked into a ``(MADAM_STAT_WIDTH,)``
+    vector — the reference backend's form of the stat partials."""
+    return jnp.stack(madam_stats_terms(code, target, new_code, gamma=gamma,
+                                       max_code=max_code, requant=requant))
 
 
 def madam_stats_dict(vec, n: int, fmt: LNSFormat,
@@ -159,9 +171,16 @@ def _packed_stats_kernel(bc_ref, w_ref, g_ref, v_ref, w_out, v_out,
     w_out[...] = ((sign_bit << (bits - 1)) | new_code.astype(jnp.int32)
                   ).astype(w_out.dtype)
     v_out[...] = v
-    stats_out[...] = madam_stats_vec(
-        code, target, new_code, gamma=gamma, max_code=max_code,
-        requant=requant).reshape(1, 1, MADAM_STAT_WIDTH)
+    # one (8, 128) f32 tile per grid step: slot k of the stat vector in
+    # row 0, lane k, zeros elsewhere (a tile-aligned, lane-dense block)
+    terms = madam_stats_terms(code, target, new_code, gamma=gamma,
+                              max_code=max_code, requant=requant)
+    row = jax.lax.broadcasted_iota(jnp.int32, _STAT_TILE, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, _STAT_TILE, 1)
+    tile = jnp.zeros(_STAT_TILE, jnp.float32)
+    for k, t in enumerate(terms):
+        tile = jnp.where((row == 0) & (lane == k), t, tile)
+    stats_out[0] = tile
 
 
 @functools.partial(
@@ -307,8 +326,8 @@ def madam_update_packed_stats_pallas(
 
     Identical (word', v') to :func:`madam_update_packed_pallas` plus a
     summed ``(MADAM_STAT_WIDTH,)`` f32 stat vector (layout at the top of
-    this module). Each tile writes its partial sums to a (1,1,W) lane and
-    the grid-shaped output is reduced here — the weights and grads are
+    this module). Each tile writes its partial sums into row 0 of its own
+    (8, 128) output block and the per-tile blocks are reduced here — the weights and grads are
     still touched exactly once in HBM. ``requant`` is the static
     ``requant_spec(...)`` tuple or ``None``. Returns
     ``(new_packed, new_v, stats_vec)``.
@@ -338,13 +357,13 @@ def madam_update_packed_stats_pallas(
         out_specs=[
             pl.BlockSpec((block_r, block_c), tile),
             pl.BlockSpec((block_r, block_c), tile),
-            pl.BlockSpec((1, 1, MADAM_STAT_WIDTH), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1,) + _STAT_TILE, lambda i, j: (i * gc + j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, C), packed.dtype),
             jax.ShapeDtypeStruct((R, C), jnp.float32),
-            jax.ShapeDtypeStruct((gr, gc, MADAM_STAT_WIDTH), jnp.float32),
+            jax.ShapeDtypeStruct((gr * gc,) + _STAT_TILE, jnp.float32),
         ],
         interpret=interpret,
     )(bc, packed, g, v)
-    return new_packed, new_v, stats.sum(axis=(0, 1))
+    return new_packed, new_v, stats[:, 0, :MADAM_STAT_WIDTH].sum(axis=0)

@@ -1,5 +1,6 @@
-"""Fused paged attention over a block-paged KV pool: double-buffered page
-DMAs, tile-local LNS decode, online softmax — decode *and* prefill shapes.
+"""Fused paged attention over a block-paged KV pool: pipelined page
+fetches, tile-local LNS decode, online softmax — decode *and* prefill
+shapes.
 
 One kernel serves both serving shapes:
 
@@ -12,25 +13,27 @@ One kernel serves both serving shapes:
   prefix-cached pages are attended but never recomputed, at kernel level
   rather than by re-gathering them into a scratch pool.
 
-The KV pools stay in HBM (``memory_space=ANY``); the kernel drives its own
-gather: the block table and per-slot lengths are scalar-prefetched into
-SMEM, and a two-deep VMEM buffer ring overlaps the DMA of page ``i+1``
-with the attention math on page ``i`` (see DESIGN.md §10). Each grid step
-is one batch row and loops only over ``ceil(lengths[b] / page)`` resident
-pages — short rows do proportionally less work, where the previous
-``(B, max_pages)`` grid paid for the worst case in every row.
+Pools are kv-head-major, ``(P, kv, page, hd)``, so one page is one
+``(kv, page, hd)`` block whose trailing dims are whole array dims — the
+Mosaic tiling rule holds at any head_dim (a manual page DMA out of a
+``(P, page, kv, hd)`` pool needs ``kv`` and ``hd`` tile-aligned, which
+smollm's 3x64 heads are not). The grid is ``(batch, max_pages)``: the
+block table and per-slot lengths are scalar-prefetched into SMEM, and the
+pools' index maps read the page id from the table, so Pallas's pipeline
+fetches page ``i+1`` while page ``i`` is attended (see DESIGN.md §10).
+Steps past a row's ``ceil(lengths[b] / page)`` resident pages repeat the
+last resident page id — an unchanged block index issues no DMA — and skip
+the math, so every resident page crosses HBM exactly once.
 
 Packed LNS pages decode tile-locally in VMEM through the one shared
 ``core.lns.lns_decode_packed`` (scales applied per position/head), so the
 kernel cannot drift from the jnp oracle. The online-softmax accumulator
-``(m, l, acc)`` lives in loop carries; the full ``(S, positions)`` score
-row never materializes.
+``(m, l, acc)`` lives in VMEM scratch across the page axis; the full
+``(S, positions)`` score row never materializes.
 
 Invalid tail positions (beyond a slot's length) are masked before the
 softmax, so block-table entries pointing at the pool's sacrificial null
-page are harmless. Head/page dims are used as-is — serving shapes are
-small and CPU CI runs this kernel in interpret mode; real-TPU tiling pads
-would go in ``ops.paged_attend_blocktable``.
+page are harmless.
 """
 from __future__ import annotations
 
@@ -45,124 +48,75 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.lns import LNSFormat, lns_decode_packed
 from repro.kernels.dispatch import resolve_interpret
 
-__all__ = ["paged_attend_pallas", "NUM_BUFFERS"]
-
-# depth of the VMEM page-buffer ring: 2 = classic double buffering
-# (prefetch page i+1 while attending page i)
-NUM_BUFFERS = 2
+__all__ = ["paged_attend_pallas"]
 
 
-def _kernel(tbl_ref, len_ref, q_ref, kp_hbm, vp_hbm, *rest, fmt, softcap,
-            sm_scale, page):
+def _resident_pages(ln, page, max_pages):
+    """Pages holding a row's ``ln`` valid positions, >= 1 (the engine
+    never serves an empty row: lengths include the token just written).
+    Bucket-padded prefill queries can push ``ln`` past the table span —
+    clamp to the table width (their outputs are discarded anyway)."""
+    return jnp.minimum((ln + page - 1) // page, max_pages)
+
+
+def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest, fmt, softcap,
+            sm_scale, seq):
     if fmt is not None:
-        ks_hbm, vs_hbm, o_ref = rest
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
-        o_ref = rest[0]
-    b = pl.program_id(0)
-    _, S, h, hd = q_ref.shape
-    kv = kp_hbm.shape[-2]
-    rep = h // kv
+        o_ref, m_ref, l_ref, acc_ref = rest
+    b, i = pl.program_id(0), pl.program_id(1)
+    _, kv, rows, hd = q_ref.shape     # rows = seq * rep, s-major
+    _, _, page, _ = k_ref.shape
+    rep = rows // seq
     ln = len_ref[b]
-    n_pages = (ln + page - 1) // page  # >= 1: the engine never serves an
-    # empty row (prompt >= 1 token and lengths include the token just
-    # written), so the warm-up DMA below is always valid. Bucket-padded
-    # prefill queries can push ln past the table span — clamp to the
-    # table width (their outputs are discarded by the caller anyway)
-    n_pages = jnp.minimum(n_pages, tbl_ref.shape[1])
 
-    def body(kbuf, vbuf, sem, ksbuf=None, vsbuf=None):
-        def dma(slot, i):
-            """Async copies moving pool page ``tbl[b, i]`` into ring slot
-            ``slot`` — one per pool operand, each on its own semaphore."""
-            pg = tbl_ref[b, i]
-            cps = [
-                pltpu.make_async_copy(kp_hbm.at[pg], kbuf.at[slot],
-                                      sem.at[slot, 0]),
-                pltpu.make_async_copy(vp_hbm.at[pg], vbuf.at[slot],
-                                      sem.at[slot, 1]),
-            ]
-            if fmt is not None:
-                cps += [
-                    pltpu.make_async_copy(ks_hbm.at[pg], ksbuf.at[slot],
-                                          sem.at[slot, 2]),
-                    pltpu.make_async_copy(vs_hbm.at[pg], vsbuf.at[slot],
-                                          sem.at[slot, 3]),
-                ]
-            return cps
+    @pl.when(i == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-        for cp in dma(0, 0):  # warm-up: page 0 in flight before the loop
-            cp.start()
+    @pl.when(i < _resident_pages(ln, page, tbl_ref.shape[1]))
+    def _attend():
+        k = k_ref[0]                          # (kv, page, hd)
+        v = v_ref[0]
+        if fmt is not None:
+            # tile-local unpack+decode through the one shared definition
+            # in core.lns — no drift from the jnp oracle
+            k = lns_decode_packed(k, fmt, jnp.float32) * \
+                ks_ref[0].astype(jnp.float32)
+            v = lns_decode_packed(v, fmt, jnp.float32) * \
+                vs_ref[0].astype(jnp.float32)
+        else:
+            k = k.astype(jnp.float32)
+            v = v.astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)      # (kv, rows, hd)
+        logits = jnp.einsum("grd,gpd->grp", q, k,
+                            preferred_element_type=jnp.float32) * sm_scale
+        if softcap is not None:
+            logits = softcap * jnp.tanh(logits / softcap)
+        # query row r sits at absolute position ln - seq + r // rep; the
+        # causal test pos <= that is spelled multiplicatively,
+        # (pos - ln + seq) * rep <= r, so no vector integer division
+        row = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+        causal = (i * page + col - ln + seq) * rep <= row
+        logits = jnp.where(causal, logits, -1e30)   # (kv, rows, page)
 
-        q = q_ref[0].astype(jnp.float32)              # (S, h, hd)
-        qg = q.reshape(S, kv, rep, hd)
-        q_pos = ln - S + jax.lax.broadcasted_iota(jnp.int32, (S, 1, 1), 0)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        pexp = jnp.exp(logits - m_new)
+        corr = jnp.exp(m_prev - m_new)              # (kv, rows, 1)
+        l_ref[...] = corr * l_ref[...] + jnp.sum(pexp, axis=-1,
+                                                 keepdims=True)
+        acc_ref[...] = corr * acc_ref[...] + jnp.einsum(
+            "grp,gpd->grd", pexp, v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
-        def step(i, carry):
-            m_prev, l_prev, acc = carry
-            cur = jax.lax.rem(i, NUM_BUFFERS)
-            nxt = jax.lax.rem(i + 1, NUM_BUFFERS)
-
-            @pl.when(i + 1 < n_pages)
-            def _prefetch():                 # overlap: next page's DMA
-                for cp in dma(nxt, i + 1):   # issues while this page's
-                    cp.start()               # attention math runs
-
-            for cp in dma(cur, i):
-                cp.wait()
-
-            k = kbuf[cur]                    # (page, kv, hd)
-            v = vbuf[cur]
-            if fmt is not None:
-                # tile-local unpack+decode through the one shared
-                # definition in core.lns — no drift from the jnp oracle
-                k = lns_decode_packed(k, fmt, jnp.float32) * \
-                    ksbuf[cur].astype(jnp.float32)
-                v = lns_decode_packed(v, fmt, jnp.float32) * \
-                    vsbuf[cur].astype(jnp.float32)
-            else:
-                k = k.astype(jnp.float32)
-                v = v.astype(jnp.float32)
-
-            logits = jnp.einsum("sgrd,pgd->sgrp", qg, k,
-                                preferred_element_type=jnp.float32)
-            logits = logits.reshape(S, h, page) * sm_scale
-            if softcap is not None:
-                logits = softcap * jnp.tanh(logits / softcap)
-            pos = i * page + jax.lax.broadcasted_iota(
-                jnp.int32, (S, 1, page), 2)
-            logits = jnp.where(pos <= q_pos, logits, -1e30)  # (S, h, page)
-
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(logits, axis=-1, keepdims=True))
-            pexp = jnp.exp(logits - m_new)            # (S, h, page)
-            corr = jnp.exp(m_prev - m_new)            # (S, h, 1)
-            l_new = corr * l_prev + jnp.sum(pexp, axis=-1, keepdims=True)
-            ctx = jnp.einsum("sgrp,pgd->sgrd",
-                             pexp.reshape(S, kv, rep, page), v,
-                             preferred_element_type=jnp.float32)
-            acc = corr * acc + ctx.reshape(S, h, hd)
-            return m_new, l_new, acc
-
-        init = (jnp.full((S, h, 1), -1e30, jnp.float32),
-                jnp.zeros((S, h, 1), jnp.float32),
-                jnp.zeros((S, h, hd), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(0, n_pages, step, init)
-        o_ref[0] = acc / jnp.maximum(l, 1e-30)
-
-    kv_dt = kp_hbm.dtype
-    scratch = {
-        "kbuf": pltpu.VMEM((NUM_BUFFERS, page, kv, hd), kv_dt),
-        "vbuf": pltpu.VMEM((NUM_BUFFERS, page, kv, hd), kv_dt),
-    }
-    n_ops = 2
-    if fmt is not None:
-        scratch["ksbuf"] = pltpu.VMEM((NUM_BUFFERS, page, kv, 1),
-                                      ks_hbm.dtype)
-        scratch["vsbuf"] = pltpu.VMEM((NUM_BUFFERS, page, kv, 1),
-                                      vs_hbm.dtype)
-        n_ops = 4
-    scratch["sem"] = pltpu.SemaphoreType.DMA((NUM_BUFFERS, n_ops))
-    pl.run_scoped(body, **scratch)
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 @functools.partial(
@@ -171,9 +125,9 @@ def _kernel(tbl_ref, len_ref, q_ref, kp_hbm, vp_hbm, *rest, fmt, softcap,
 )
 def paged_attend_pallas(
     q: jax.Array,            # (B, S, h, hd)
-    kp: jax.Array,           # (P, page, kv, hd) packed words or dense
+    kp: jax.Array,           # (P, kv, page, hd) packed words or dense
     vp: jax.Array,
-    k_scale: Optional[jax.Array],   # (P, page, kv, 1) when fmt is set
+    k_scale: Optional[jax.Array],   # (P, kv, page, 1) when fmt is set
     v_scale: Optional[jax.Array],
     block_table: jax.Array,  # (B, max_pages) int32
     lengths: jax.Array,      # (B,) int32 valid positions per slot
@@ -188,32 +142,54 @@ def paged_attend_pallas(
     ``lengths`` counts each slot's valid positions *including* the S just
     written, so query ``s`` sits at absolute position ``lengths - S + s``
     (matching ``dispatch._paged_attend_reference``). Must be >= 1 per row.
+
+    Queries enter the kernel grouped by KV head, ``(B, kv, S*rep, hd)``
+    (GQA stays grouped, never repeated), so each page is one batched
+    ``(kv, rows, hd) x (kv, page, hd)`` matmul.
     """
     interpret = resolve_interpret(interpret)
     B, S, h, hd = q.shape
-    _, page, kv, _ = kp.shape
+    _, kv, page, _ = kp.shape
+    rep = h // kv
+    rows = S * rep
+    qg = q.reshape(B, S, kv, rep, hd).transpose(0, 2, 1, 3, 4).reshape(
+        B, kv, rows, hd)
 
-    in_specs = [
-        pl.BlockSpec((1, S, h, hd), lambda b, tbl, ln: (b, 0, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),   # KV pools stay in HBM;
-        pl.BlockSpec(memory_space=pltpu.ANY),   # the kernel DMAs pages
-    ]
-    args = [q, kp, vp]
+    max_pages = block_table.shape[1]
+
+    def page_ix(b, i, tbl, ln):
+        # past the resident pages, stay on the last one: no new DMA
+        last = _resident_pages(ln[b], page, max_pages) - 1
+        return (tbl[b, jnp.minimum(i, last)], 0, 0, 0)
+
+    rows_spec = pl.BlockSpec((1, kv, rows, hd),
+                             lambda b, i, tbl, ln: (b, 0, 0, 0))
+    pool_spec = pl.BlockSpec((1, kv, page, hd), page_ix)
+    in_specs = [rows_spec, pool_spec, pool_spec]
+    args = [qg, kp, vp]
     if fmt is not None:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
+        scale_spec = pl.BlockSpec((1, kv, page, 1), page_ix)
+        in_specs += [scale_spec, scale_spec]
         args += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B,),
+        grid=(B, max_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, S, h, hd), lambda b, tbl, ln: (b, 0, 0, 0)),
+        out_specs=rows_spec,
+        scratch_shapes=[pltpu.VMEM((kv, rows, 1), jnp.float32),
+                        pltpu.VMEM((kv, rows, 1), jnp.float32),
+                        pltpu.VMEM((kv, rows, hd), jnp.float32)],
     )
     kernel = functools.partial(_kernel, fmt=fmt, softcap=softcap,
-                               sm_scale=sm_scale, page=page)
-    return pl.pallas_call(
+                               sm_scale=sm_scale, seq=S)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, h, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, kv, rows, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, lengths, *args)
+    return out.reshape(B, kv, S, rep, hd).transpose(0, 2, 1, 3, 4).reshape(
+        B, S, h, hd)

@@ -1,12 +1,16 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 DOC = """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above run before ANY other import — jax locks the device
-count at first init, and the production meshes need 512 placeholder host
-devices. Smoke tests and benchmarks never import this module, so they see
-the real single CPU device.
+The lines above run before ANY other import — jax locks the device count
+at first init, and the production meshes need 512 placeholder host
+devices. The CPU platform is pinned too: this is a host-side placeholder
+tool, and neither it nor its ``--all`` children may take an attached
+accelerator (a chip belongs to one process at a time). Smoke tests and
+benchmarks never import this module, so they see the real single CPU
+device.
 
 For each cell the step function (train / prefill / decode per the shape's
 kind) is jitted with explicit in_shardings from the logical rules, lowered

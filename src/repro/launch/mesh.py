@@ -7,9 +7,38 @@ import and only then builds meshes.
 """
 from __future__ import annotations
 
-import jax
+import os
 
-__all__ = ["make_production_mesh", "make_host_mesh"]
+import jax
+from jax.sharding import AxisType
+
+__all__ = ["EXACT_ROUNDING_FLAG", "make_mesh", "make_production_mesh",
+           "make_host_mesh", "require_exact_rounding"]
+
+# XLA:TPU may keep f32 precision across the bf16 ops that one fusion spans
+# (excess precision). A mesh program's collectives cut fusions in other
+# places than the one-device program's, so the two would round at
+# different points and their token streams part at the first near-tie.
+EXACT_ROUNDING_FLAG = "--xla_allow_excess_precision=false"
+
+
+def require_exact_rounding() -> None:
+    """Round every bf16 op to bf16 wherever fusion puts it, so a
+    mesh-sharded serving program computes bit for bit what the one-device
+    program does (DESIGN.md §12). XLA reads its flags when JAX first
+    initializes a backend: call this before that, in an entry point's
+    ``main()``; afterwards it has no effect."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if EXACT_ROUNDING_FLAG not in flags:
+        os.environ["XLA_FLAGS"] = f"{flags} {EXACT_ROUNDING_FLAG}".strip()
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding model
+    ``distributed/sharding.py`` is written for (logical-rule
+    ``with_sharding_constraint`` hints the partitioner completes). Newer
+    JAX defaults to ``Explicit`` axes, which reject those constraints."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,7 +46,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     2-pod data-parallel axis (2x16x16 = 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -36,4 +65,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
             f"needs {data * model} devices but only {n} are available; "
             f"set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{data * model} (before importing jax) or shrink the mesh")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

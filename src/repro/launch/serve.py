@@ -31,7 +31,8 @@ from repro.configs import get_config, get_rules, get_smoke_config
 from repro.core.lns import LNSFormat
 from repro.core.quantizer import QuantConfig
 from repro.distributed.sharding import shard_ctx
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh, require_exact_rounding
 from repro.optim.madam import MadamConfig
 from repro.serving import Engine, max_trace_len, synthetic_trace
 from repro.training import init_train_state
@@ -136,6 +137,8 @@ def main():
                          "attribution, printed after an offline run")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    require_exact_rounding()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     qcfg = QuantConfig.lns_madam()
@@ -238,6 +241,13 @@ def main():
             for name, row in kernel_stats.get().items():
                 print(f"  kernel {name}: calls={row['calls']} "
                       f"traces={row['traces']} time={row['time_s']:.4f}s")
+    # the engine isolates a failing admission so that an online gateway
+    # keeps serving; an offline run that lost requests has failed
+    errors = sum(rs.finish_reason == "error" for rs in engine.aborted)
+    if int(agg["completed"]) != args.requests or errors:
+        raise SystemExit(
+            f"serving failed: {int(agg['completed'])}/{args.requests} "
+            f"requests completed, {errors} ended in error")
 
 
 if __name__ == "__main__":

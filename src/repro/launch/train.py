@@ -24,6 +24,8 @@ if os.environ.get("REPRO_TPU_FLAGS"):
         os.environ.get("XLA_FLAGS", "") + " " + TPU_PERF_FLAGS)
 
 import argparse
+import math
+import tempfile
 import time
 
 import jax
@@ -34,6 +36,7 @@ from repro.configs import get_config, get_rules, get_smoke_config
 from repro.core.quantizer import QuantConfig
 from repro.distributed.params_sharding import batch_shardings
 from repro.distributed.sharding import shard_ctx
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim.madam import MadamConfig
 from repro.training import build_train_step, init_train_state
@@ -53,7 +56,9 @@ def main():
     ap.add_argument("--accum-steps", type=int, default=1)
     ap.add_argument("--format", default="lns8",
                     choices=["lns8", "fp8", "fp32"])
-    ap.add_argument("--ckpt-dir", default="/tmp/lns_madam_ckpt")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(),
+                                         "lns_madam_ckpt"))
     ap.add_argument("--save-every", type=int, default=20)
     ap.add_argument("--trace-dir", default=None, metavar="DIR",
                     help="collect per-layer numerics telemetry and export "
@@ -76,6 +81,7 @@ def main():
                          "--progress-every steps through the observer")
     ap.add_argument("--progress-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     qcfg = {"lns8": QuantConfig.lns_madam(), "fp8": QuantConfig.fp8(),
@@ -146,6 +152,13 @@ def main():
             for name, row in kernel_stats.get().items():
                 print(f"  kernel {name}: calls={row['calls']} "
                       f"traces={row['traces']} time={row['time_s']:.4f}s")
+    # recovered failures are the supervisor's business; an unfinished run
+    # or a non-finite loss it committed is the caller's, so it must fail
+    bad = [i for i, x in enumerate(report.losses) if not math.isfinite(x)]
+    if report.steps_done < args.steps or bad:
+        raise SystemExit(
+            f"training failed: {report.steps_done}/{args.steps} steps "
+            f"done, non-finite loss at steps {bad}")
 
 
 if __name__ == "__main__":

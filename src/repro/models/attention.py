@@ -287,22 +287,24 @@ def init_paged_kv_cache(batch: int, num_pages: int, page_size: int,
 
     Wire format matches the dense cache: with ``cfg.kv_cache_bits`` pages
     store packed LNS words + per-(pos, head) power-of-two scales
-    (``_kv_encode``), decoded on read.
+    (``_kv_encode``), decoded on read. Pages are kv-head-major,
+    ``(P, kv, page, hd)``: one page is one whole-dims block for the paged
+    attention kernel (see ``kernels/paged_attend.py``).
     """
     kv, hd = cfg.num_kv_heads, cfg.head_dim
     P = num_pages + 1
     if cfg.kv_cache_bits:
         return {
-            "kp": jnp.zeros((P, page_size, kv, hd), jnp.uint8),
-            "vp": jnp.zeros((P, page_size, kv, hd), jnp.uint8),
-            "kp_scale": jnp.ones((P, page_size, kv, 1), jnp.bfloat16),
-            "vp_scale": jnp.ones((P, page_size, kv, 1), jnp.bfloat16),
+            "kp": jnp.zeros((P, kv, page_size, hd), jnp.uint8),
+            "vp": jnp.zeros((P, kv, page_size, hd), jnp.uint8),
+            "kp_scale": jnp.ones((P, kv, page_size, 1), jnp.bfloat16),
+            "vp_scale": jnp.ones((P, kv, page_size, 1), jnp.bfloat16),
             "idx": jnp.zeros((batch,), jnp.int32),
         }
     dt = cfg.compute_dtype
     return {
-        "kp": jnp.zeros((P, page_size, kv, hd), dt),
-        "vp": jnp.zeros((P, page_size, kv, hd), dt),
+        "kp": jnp.zeros((P, kv, page_size, hd), dt),
+        "vp": jnp.zeros((P, kv, page_size, hd), dt),
         "idx": jnp.zeros((batch,), jnp.int32),
     }
 
@@ -326,7 +328,7 @@ def _paged_attend(q, k_new, v_new, cache, cfg: ArchConfig, *,
     from repro.kernels import dispatch
     B, S, h, hd = q.shape
     pool_k = cache["kp"]
-    page = pool_k.shape[1]
+    page = pool_k.shape[2]
     mp = block_table.shape[1]
     idx = cache["idx"]  # (B,) tokens already cached, per slot
     pos = idx[:, None] + jnp.arange(S)  # (B, S) absolute write positions
@@ -350,12 +352,12 @@ def _paged_attend(q, k_new, v_new, cache, cfg: ArchConfig, *,
     new_cache = dict(cache)
     fpg, foff = pg.reshape(-1), off.reshape(-1)
     for key, new in store:
-        flat = new.reshape((B * S,) + new.shape[2:])
-        new_cache[key] = cache[key].at[fpg, foff].set(
+        flat = new.reshape((B * S,) + new.shape[2:])   # (B*S, kv, ·)
+        new_cache[key] = cache[key].at[fpg, :, foff].set(
             flat.astype(cache[key].dtype), mode="drop")
         # pool pages stay head-sharded across the mesh model axis (pages and
         # page offsets are shard-local views of one logical block table)
-        new_cache[key] = shard(new_cache[key], None, None, "kv_heads", None)
+        new_cache[key] = shard(new_cache[key], None, "kv_heads", None, None)
     new_cache["idx"] = idx + S
 
     out = dispatch.paged_attend(

@@ -9,6 +9,7 @@ import pytest
 
 from repro.checkpoint import CheckpointManager
 from repro.core.lns import LNSFormat
+from repro.launch.mesh import make_mesh
 from repro.optim.madam import LNSWeight
 
 _FMT = LNSFormat(bits=16, gamma=8 * (1 << 8))
@@ -67,7 +68,7 @@ def test_atomicity_no_partial_latest(tmp_path, key):
 def test_elastic_restore_with_shardings(tmp_path, key):
     """Restore places arrays with explicitly-provided (new-mesh) shardings."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     m = CheckpointManager(str(tmp_path))
     st = {"w": jnp.arange(8.0)}
     m.save(1, st, async_=False)

@@ -4,10 +4,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.lns import LNSFormat
+from repro.launch.mesh import make_mesh
 from repro.optim.compression import (error_feedback_update,
                                      lns_compressed_psum, sign_majority_psum)
 
@@ -41,14 +41,14 @@ def test_plain_quantize_accumulates_bias(key):
 
 
 def test_lns_compressed_psum_single_device(key):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     grads = {"w": jax.random.normal(key, (16,))}
 
     def f(g):
         out, _ = lns_compressed_psum(g, "data", FMT)
         return out
 
-    out = shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P())(grads)
+    out = jax.shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P())(grads)
     # single participant: psum of the quantized grad == quantized grad
     from repro.core.lns import lns_quantize
     np.testing.assert_allclose(np.asarray(out["w"]),
@@ -57,13 +57,13 @@ def test_lns_compressed_psum_single_device(key):
 
 
 def test_sign_majority_psum_single_device(key):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     grads = {"w": jax.random.normal(key, (16,))}
 
     def f(g):
         return sign_majority_psum(g, "data")
 
-    out = shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P())(grads)
+    out = jax.shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P())(grads)
     np.testing.assert_array_equal(np.asarray(out["w"]),
                                   np.sign(np.asarray(grads["w"])))
 

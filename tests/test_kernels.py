@@ -4,8 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
-from repro.testing import given, settings, st
 from repro.core.lns import LNSFormat, compute_scale, lns_encode, lns_pack
 from repro.kernels import lns_matmul, lns_qmatmul, madam_step, quantize_pack
 from repro.kernels import ref as kref

@@ -3,8 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
-from repro.testing import given, settings, st
 from repro.core.lns import (LNSFormat, compute_scale, lns_decode, lns_encode,
                             lns_pack, lns_quantize, lns_requant_packed,
                             lns_unpack, pow2_scale, quantization_gap)

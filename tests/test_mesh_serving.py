@@ -96,6 +96,26 @@ def test_mesh_matches_single_device_tokens(layout, spec_k):
 
 
 @requires_mesh
+@pytest.mark.interpret
+def test_mesh_pallas_kernels_match_single_device_tokens():
+    """The Pallas backend under a (1, 4) mesh: every dispatched kernel runs
+    inside shard_map (Mosaic cannot be auto-partitioned) — GEMMs
+    column-parallel, paged attention head-sharded (gemma3's 2 kv heads
+    shard model=2 on a (2, 2) mesh), the rest replicated — and the streams
+    still equal the single-device Pallas engine's."""
+    from repro.kernels import dispatch
+
+    kw = dict(num_slots=2, page_size=8, alloc_policy="ondemand",
+              num_pages=12)
+    with dispatch.configured(backend="pallas", interpret=True):
+        for arch, shape in (("smollm-135m", (1, 4)), ("gemma3-12b", (2, 2))):
+            cfg, base, sharded = _engines(arch, mesh_shape=shape, **kw)
+            base.run(_trace(cfg, n=4))
+            sharded.run(_trace(cfg, n=4))
+            assert _tokens(sharded) == _tokens(base), arch
+
+
+@requires_mesh
 def test_mesh_weights_actually_sharded():
     """Guard against a vacuous pass: the (2,2) mesh engine must hold its
     mlp weights column-parallel over the model axis (d_ff divides)."""
@@ -153,17 +173,17 @@ def test_paged_attend_shard_map_bitwise(quantized):
     q = jax.numpy.asarray(rng.standard_normal((B, S, h, hd)), jax.numpy.float32)
     if quantized:
         kp = jax.numpy.asarray(
-            rng.integers(0, 255, (pages + 1, page, kv, hd)), jax.numpy.uint8)
+            rng.integers(0, 255, (pages + 1, kv, page, hd)), jax.numpy.uint8)
         vp = jax.numpy.asarray(
-            rng.integers(0, 255, (pages + 1, page, kv, hd)), jax.numpy.uint8)
-        ks = jax.numpy.ones((pages + 1, page, kv, 1), jax.numpy.bfloat16)
-        vs = jax.numpy.ones((pages + 1, page, kv, 1), jax.numpy.bfloat16)
+            rng.integers(0, 255, (pages + 1, kv, page, hd)), jax.numpy.uint8)
+        ks = jax.numpy.ones((pages + 1, kv, page, 1), jax.numpy.bfloat16)
+        vs = jax.numpy.ones((pages + 1, kv, page, 1), jax.numpy.bfloat16)
         fmt = LNSFormat(bits=8, gamma=8)
     else:
         kp = jax.numpy.asarray(
-            rng.standard_normal((pages + 1, page, kv, hd)), jax.numpy.float32)
+            rng.standard_normal((pages + 1, kv, page, hd)), jax.numpy.float32)
         vp = jax.numpy.asarray(
-            rng.standard_normal((pages + 1, page, kv, hd)), jax.numpy.float32)
+            rng.standard_normal((pages + 1, kv, page, hd)), jax.numpy.float32)
         ks = vs = None
         fmt = None
     bt = jax.numpy.asarray([[0, 2, pages], [1, 3, pages]], jax.numpy.int32)

@@ -16,8 +16,8 @@ pytestmark = pytest.mark.interpret
 def _setup(seed=0, B=3, h=6, kv=2, hd=16, page=4, mp=5, P=11, S=1):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(B, S, h, hd)), jnp.float32)
-    kd = rng.normal(size=(P + 1, page, kv, hd)).astype(np.float32)
-    vd = rng.normal(size=(P + 1, page, kv, hd)).astype(np.float32)
+    kd = rng.normal(size=(P + 1, kv, page, hd)).astype(np.float32)
+    vd = rng.normal(size=(P + 1, kv, page, hd)).astype(np.float32)
     tbl = jnp.asarray(rng.integers(0, P, (B, mp)), jnp.int32)
     lengths = jnp.asarray(rng.integers(max(S, 1), mp * page + 1, (B,)),
                           jnp.int32)
@@ -212,10 +212,11 @@ def test_fused_sample_mixed_temperature_bit_exact():
 
 
 def test_engine_decode_routes_through_kernel(monkeypatch):
-    """REPRO_KERNEL_BACKEND=pallas + interpret: the paged engine's decode
-    path reaches the kernel and still matches the reference backend."""
+    """Pallas backend + interpret: the paged engine's decode path reaches
+    the kernel and still matches the reference backend."""
     monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
     from repro.configs import get_smoke_config
+    from repro.kernels import dispatch
     from repro.core.quantizer import QuantConfig
     from repro.optim.madam import MadamConfig
     from repro.serving import Engine, Request
@@ -237,10 +238,9 @@ def test_engine_decode_routes_through_kernel(monkeypatch):
     ref_eng.run(mk())
     ref = {rs.request.rid: rs.generated for rs in ref_eng.finished}
 
-    import dataclasses
-    qk = dataclasses.replace(qcfg, backend="pallas")
-    kern_eng = Engine(cfg, qk, mcfg, params, num_slots=2, max_len=16,
-                      page_size=4)
-    kern_eng.run(mk())
+    with dispatch.configured(backend="pallas"):
+        kern_eng = Engine(cfg, qcfg, mcfg, params, num_slots=2, max_len=16,
+                          page_size=4)
+        kern_eng.run(mk())
     got = {rs.request.rid: rs.generated for rs in kern_eng.finished}
     assert ref == got

@@ -12,12 +12,13 @@ from repro.distributed.params_sharding import (batch_shardings,
                                                tree_shardings)
 from repro.distributed.sharding import (LOGICAL_RULES, shard, shard_ctx,
                                         spec_for)
+from repro.launch.mesh import make_mesh
 from repro.models import ArchConfig, init_params
 from repro.optim.madam import LNSWeight, MadamConfig, init_lns_params, madam_lns
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_spec_resolution_drops_missing_axes():
@@ -208,7 +209,7 @@ def test_make_host_mesh_raises_on_oversubscription():
 def test_serving_rules_divisibility_gates():
     from repro.distributed.sharding import serving_rules
     from repro.models import ArchConfig
-    mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh2 = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         axis_names = ("data", "model")

@@ -42,7 +42,8 @@ packed-LNS weights and decode step:
     against the obs-disabled ``paged`` row from the same interleave
     group is the overhead gate: ``obs_overhead_pct`` must stay near
     zero, proving tracing is close to free, and the observer's
-    prefill/decode/spec time breakdown rides along as an attachment.
+    time breakdown (device wait, host work inside steps, time outside
+    them) rides along as an attachment.
 
 All timed paths are run once to warm the jit caches and then timed over
 ``REPLAYS`` replays, keeping each harness's best. The engine harnesses
@@ -269,7 +270,8 @@ def run(requests: int = 24, slots: int = 4, prompt_len: int = 16,
     observer.clear()
     engines["obs"].reset()
     agg_bd = engines["obs"].run(trace)
-    time_breakdown = observer.time_breakdown(agg_bd["wall_s"])
+    time_breakdown = observer.time_breakdown(
+        engines["obs"].phase_stats(), agg_bd["wall_s"])
     rows.append(csv_row(
         "serving_obs", agg_o["wall_s"] * 1e6,
         f"tok_s={tps_obs:.1f} overhead_vs_paged={obs_overhead_pct:.2f}% "

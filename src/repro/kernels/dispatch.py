@@ -27,15 +27,9 @@ Selection precedence (one rule for every op, highest first):
 Env vars and :func:`configure` state are read at trace time — set them
 before the first jit of a step function.
 
-Kernel-time attribution (DESIGN.md §13): every dispatched op checks
-``repro.obs.kernel_stats`` for an active collector. Disabled — the
-default — that is one module-global load per call (and these ops run at
-*trace* time inside the serving jits, so the per-token hot loop never
-sees even that). Enabled, calls are attributed by
-(op, backend, bitwidth): trace-time entries bump compile counters,
-eager calls record launch walltime, and a sampling knob occasionally
-blocks until ready for true device time. :func:`profiler_trace`
-(re-exported) wraps ``jax.profiler`` for whole-program XLA traces.
+Kernel time is read from a profiler trace (DESIGN.md §13): each Pallas
+kernel runs under its jitted ``*_pallas`` wrapper, whose name the
+compiled program's custom call and the device trace's events carry.
 """
 from __future__ import annotations
 
@@ -50,8 +44,6 @@ import jax.numpy as jnp
 from repro.core.lns import (LNSFormat, compute_scale, lns_decode_packed,
                             lns_encode, lns_pack, lns_requant_packed,
                             lns_unpack, lns_word_dtype)
-from repro.obs import kernel_stats
-from repro.obs.kernel_stats import profiler_trace
 
 __all__ = [
     "BACKENDS",
@@ -63,8 +55,6 @@ __all__ = [
     "default_backend",
     "resolve_backend",
     "resolve_interpret",
-    "kernel_stats",
-    "profiler_trace",
     "qmatmul",
     "encode_pack",
     "requant_pack",
@@ -215,18 +205,6 @@ def qmatmul(pa: jax.Array, pb: jax.Array, fmt: LNSFormat,
             backend: Optional[str] = None,
             interpret: Optional[bool] = None) -> jax.Array:
     """Packed ``pa (M,K) @ pb (K,N)`` -> f32, per-row/col scale epilogue."""
-    if kernel_stats.active() is not None:
-        return kernel_stats.observe(
-            "qmatmul", resolve_backend(backend), fmt.bits, pa, _qmatmul,
-            pa, pb, fmt, scale_a, scale_b, compute_dtype=compute_dtype,
-            backend=backend, interpret=interpret)
-    return _qmatmul(pa, pb, fmt, scale_a, scale_b,
-                    compute_dtype=compute_dtype, backend=backend,
-                    interpret=interpret)
-
-
-def _qmatmul(pa, pb, fmt, scale_a=None, scale_b=None, *,
-             compute_dtype=jnp.bfloat16, backend=None, interpret=None):
     if resolve_backend(backend) == "pallas":
         from jax.sharding import PartitionSpec as P
 
@@ -264,16 +242,6 @@ def encode_pack(x: jax.Array, fmt: LNSFormat, scale_axis: Optional[int] = None,
     Returns ``(packed (R,C), scale (R,1) f32)``; ``scale_axis=0`` keeps
     per-row scales, ``None`` is per-tensor (broadcast to (R,1)).
     """
-    if kernel_stats.active() is not None:
-        return kernel_stats.observe(
-            "encode_pack", resolve_backend(backend), fmt.bits, x,
-            _encode_pack, x, fmt, scale_axis, backend=backend,
-            interpret=interpret)
-    return _encode_pack(x, fmt, scale_axis, backend=backend,
-                        interpret=interpret)
-
-
-def _encode_pack(x, fmt, scale_axis=None, *, backend=None, interpret=None):
     if resolve_backend(backend) == "pallas":
         from repro.kernels.ops import quantize_pack
         interp = resolve_interpret(interpret)
@@ -298,16 +266,6 @@ def requant_pack(packed: jax.Array, src: LNSFormat, dst: LNSFormat, *,
     sign preserved, scales untouched. Both backends are bit-identical: the
     Pallas kernel body traces :func:`lns_requant_packed` directly.
     """
-    if kernel_stats.active() is not None:
-        return kernel_stats.observe(
-            "requant_pack", resolve_backend(backend), dst.bits, packed,
-            _requant_pack, packed, src, dst, backend=backend,
-            interpret=interpret)
-    return _requant_pack(packed, src, dst, backend=backend,
-                         interpret=interpret)
-
-
-def _requant_pack(packed, src, dst, *, backend=None, interpret=None):
     if resolve_backend(backend) == "pallas":
         from repro.kernels.ops import requant_pack as requant_pack_op
         interp = resolve_interpret(interpret)
@@ -340,20 +298,6 @@ def madam_step(packed: jax.Array, g: jax.Array, v: jax.Array,
     ``requant_fmt`` names a coarser forward grid) the fraction of codes
     the B_U -> B_W re-grid will clamp.
     """
-    if kernel_stats.active() is not None:
-        return kernel_stats.observe(
-            "madam_step", resolve_backend(backend), fmt.bits, packed,
-            _madam_step, packed, g, v, count, fmt, lr=lr, beta=beta,
-            eps=eps, with_stats=with_stats, requant_fmt=requant_fmt,
-            backend=backend, interpret=interpret)
-    return _madam_step(packed, g, v, count, fmt, lr=lr, beta=beta, eps=eps,
-                       with_stats=with_stats, requant_fmt=requant_fmt,
-                       backend=backend, interpret=interpret)
-
-
-def _madam_step(packed, g, v, count, fmt, *, lr, beta=0.999, eps=1e-30,
-                with_stats=False, requant_fmt=None, backend=None,
-                interpret=None):
     from repro.kernels.madam_update import madam_stats_dict, requant_spec
     shape = packed.shape
     if packed.ndim < 2:
@@ -419,21 +363,6 @@ def paged_attend(q: jax.Array, kp: jax.Array, vp: jax.Array,
     placement lives here, in the dispatch layer, so the jnp reference and
     the Pallas kernel stay bit-comparable shard for shard.
     """
-    if kernel_stats.active() is not None:
-        return kernel_stats.observe(
-            "paged_attend", resolve_backend(backend),
-            fmt.bits if fmt is not None else 0, q, _paged_attend,
-            q, kp, vp, k_scale, v_scale, block_table, lengths, fmt=fmt,
-            softcap=softcap, sm_scale=sm_scale, backend=backend,
-            interpret=interpret)
-    return _paged_attend(q, kp, vp, k_scale, v_scale, block_table, lengths,
-                         fmt=fmt, softcap=softcap, sm_scale=sm_scale,
-                         backend=backend, interpret=interpret)
-
-
-def _paged_attend(q, kp, vp, k_scale, v_scale, block_table, lengths, *,
-                  fmt=None, softcap=None, sm_scale, backend=None,
-                  interpret=None):
     use_pallas = resolve_backend(backend) == "pallas"
     interp = resolve_interpret(interpret) if use_pallas else None
 
@@ -486,16 +415,6 @@ def fused_sample(logits: jax.Array, gumbel: Optional[jax.Array],
     request seed/step), so a seeded request replays token-for-token on
     either backend; the kernel fuses only the scale/add/argmax epilogue.
     """
-    if kernel_stats.active() is not None:
-        return kernel_stats.observe(
-            "fused_sample", resolve_backend(backend), 0, logits,
-            _fused_sample, logits, gumbel, temp, backend=backend,
-            interpret=interpret)
-    return _fused_sample(logits, gumbel, temp, backend=backend,
-                         interpret=interpret)
-
-
-def _fused_sample(logits, gumbel, temp, *, backend=None, interpret=None):
     if resolve_backend(backend) == "pallas":
         from repro.kernels.ops import fused_sample as fused_sample_op
         interp = resolve_interpret(interpret)

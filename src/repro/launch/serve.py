@@ -131,10 +131,9 @@ def main():
                          "DIR when the run ends")
     ap.add_argument("--jax-profile", default=None, metavar="DIR",
                     help="wrap the run in a jax.profiler trace "
-                         "(TensorBoard format) written to DIR")
-    ap.add_argument("--kernel-stats", action="store_true",
-                    help="per-(op, backend, bitwidth) kernel-time "
-                         "attribution, printed after an offline run")
+                         "(TensorBoard format) written to DIR: the "
+                         "engine's repro.engine.* phase spans beside the "
+                         "device ops, each op under its model scope")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     require_exact_rounding()
@@ -167,9 +166,6 @@ def main():
         if args.trace_dir:
             from repro.obs import EngineObserver
             observer = EngineObserver()
-        if args.kernel_stats:
-            from repro.obs import kernel_stats
-            kernel_stats.enable()
         engine = Engine(cfg, qcfg, mcfg, state.params,
                         num_slots=args.slots, max_len=max_len,
                         page_size=args.page_size, num_pages=args.num_pages,
@@ -194,8 +190,7 @@ def main():
                                 gen_len=args.gen_len, lengths=lengths,
                                 rate=args.rate, seed=args.seed)
         if args.jax_profile:
-            from repro.obs.kernel_stats import profiler_trace
-            with profiler_trace(args.jax_profile):
+            with jax.profiler.trace(args.jax_profile):
                 agg = engine.run(trace)
         else:
             agg = engine.run(trace)
@@ -230,17 +225,12 @@ def main():
             print(f"  req {rs.request.rid}: prompt {rs.request.prompt_len} "
                   f"-> {len(rs.generated)} new tokens, sample {head}")
         if observer is not None:
-            bd = observer.time_breakdown(agg["wall_s"])
-            print(f"time breakdown: prefill {bd.get('prefill_share', 0):.1%} "
-                  f"decode {bd.get('decode_share', 0):.1%} "
-                  f"spec {bd.get('spec_share', 0):.1%} "
-                  f"host {bd.get('host_share', 0):.1%}")
+            bd = observer.time_breakdown(engine.phase_stats(),
+                                         agg["wall_s"])
+            print(f"time breakdown: device wait {bd.get('wait_share', 0):.1%} "
+                  f"host {bd.get('host_share', 0):.1%} "
+                  f"outside steps {bd.get('outside_share', 0):.1%}")
             print("trace:", observer.export(args.trace_dir, tag=cfg.name))
-        if args.kernel_stats:
-            from repro.obs import kernel_stats
-            for name, row in kernel_stats.get().items():
-                print(f"  kernel {name}: calls={row['calls']} "
-                      f"traces={row['traces']} time={row['time_s']:.4f}s")
     # the engine isolates a failing admission so that an online gateway
     # keeps serving; an offline run that lost requests has failed
     errors = sum(rs.finish_reason == "error" for rs in engine.aborted)

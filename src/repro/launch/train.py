@@ -70,10 +70,8 @@ def main():
                          "loss, wall time, per-layer LNS health)")
     ap.add_argument("--jax-profile", default=None, metavar="DIR",
                     help="wrap the run in a jax.profiler trace "
-                         "(TensorBoard format) written to DIR")
-    ap.add_argument("--kernel-stats", action="store_true",
-                    help="per-(op, backend, bitwidth) kernel-time "
-                         "attribution, printed after the run")
+                         "(TensorBoard format) written to DIR, device ops "
+                         "under the model's named scopes")
     ap.add_argument("--quiet", default=True,
                     type=lambda s: s.lower() not in ("0", "false", "no"),
                     metavar="BOOL",
@@ -96,9 +94,6 @@ def main():
         observer = NumericsObserver(log_path=args.numerics_log,
                                     quiet=args.quiet,
                                     progress_every=args.progress_every)
-    if args.kernel_stats:
-        from repro.obs import kernel_stats
-        kernel_stats.enable()
 
     with shard_ctx(mesh, rules):
         state = init_train_state(jax.random.PRNGKey(0), cfg, mcfg)
@@ -125,8 +120,7 @@ def main():
 
         t0 = time.monotonic()
         if args.jax_profile:
-            from repro.obs.kernel_stats import profiler_trace
-            with profiler_trace(args.jax_profile):
+            with jax.profiler.trace(args.jax_profile):
                 report = run()
         else:
             report = run()
@@ -147,11 +141,6 @@ def main():
                 print("trace:", observer.export(args.trace_dir,
                                                 tag=cfg.name))
             observer.close()
-        if args.kernel_stats:
-            from repro.obs import kernel_stats
-            for name, row in kernel_stats.get().items():
-                print(f"  kernel {name}: calls={row['calls']} "
-                      f"traces={row['traces']} time={row['time_s']:.4f}s")
     # recovered failures are the supervisor's business; an unfinished run
     # or a non-finite loss it committed is the caller's, so it must fail
     bad = [i for i, x in enumerate(report.losses) if not math.isfinite(x)]

@@ -203,8 +203,9 @@ def attn_apply(
         kf = jnp.repeat(k, rep, axis=2)
         vf = jnp.repeat(v, rep, axis=2)
         q, kf, vf = _shard_qkv(q, kf, vf, heads_div)
-        out = flash_attention(q, kf, vf, window=window,
-                              softcap=cfg.attn_logit_softcap)
+        with jax.named_scope("core"):
+            out = flash_attention(q, kf, vf, window=window,
+                                  softcap=cfg.attn_logit_softcap)
         new_cache = None
     elif is_paged_cache(cache):
         assert window is None, "paged KV pools do not serve ring buffers"
@@ -212,7 +213,8 @@ def attn_apply(
         out, new_cache = _paged_attend(q, k, v, cache, cfg,
                                        block_table=block_table, qcfg=qcfg)
     else:
-        out, cache = _decode_attend(q, k, v, cache, cfg, window=window)
+        with jax.named_scope("core"):  # the dense row write included
+            out, cache = _decode_attend(q, k, v, cache, cfg, window=window)
         new_cache = cache
 
     out = out.reshape(B, S, h * hd)
@@ -340,33 +342,37 @@ def _paged_attend(q, k_new, v_new, cache, cfg: ArchConfig, *,
     pg = jnp.where(pos < mp * page, pg, pool_k.shape[0])
     off = pos % page
 
-    quant = bool(cfg.kv_cache_bits)
-    if quant:
-        pk_new, sk_new = _kv_encode(k_new, cfg)
-        pv_new, sv_new = _kv_encode(v_new, cfg)
-        store = (("kp", pk_new), ("vp", pv_new),
-                 ("kp_scale", sk_new), ("vp_scale", sv_new))
-    else:
-        store = (("kp", k_new), ("vp", v_new))
+    with jax.named_scope("kv_write"):
+        quant = bool(cfg.kv_cache_bits)
+        if quant:
+            pk_new, sk_new = _kv_encode(k_new, cfg)
+            pv_new, sv_new = _kv_encode(v_new, cfg)
+            store = (("kp", pk_new), ("vp", pv_new),
+                     ("kp_scale", sk_new), ("vp_scale", sv_new))
+        else:
+            store = (("kp", k_new), ("vp", v_new))
 
-    new_cache = dict(cache)
-    fpg, foff = pg.reshape(-1), off.reshape(-1)
-    for key, new in store:
-        flat = new.reshape((B * S,) + new.shape[2:])   # (B*S, kv, ·)
-        new_cache[key] = cache[key].at[fpg, :, foff].set(
-            flat.astype(cache[key].dtype), mode="drop")
-        # pool pages stay head-sharded across the mesh model axis (pages and
-        # page offsets are shard-local views of one logical block table)
-        new_cache[key] = shard(new_cache[key], None, "kv_heads", None, None)
-    new_cache["idx"] = idx + S
+        new_cache = dict(cache)
+        fpg, foff = pg.reshape(-1), off.reshape(-1)
+        for key, new in store:
+            flat = new.reshape((B * S,) + new.shape[2:])   # (B*S, kv, ·)
+            new_cache[key] = cache[key].at[fpg, :, foff].set(
+                flat.astype(cache[key].dtype), mode="drop")
+            # pool pages stay head-sharded across the mesh model axis
+            # (pages and page offsets are shard-local views of one logical
+            # block table)
+            new_cache[key] = shard(new_cache[key], None, "kv_heads", None,
+                                   None)
+        new_cache["idx"] = idx + S
 
-    out = dispatch.paged_attend(
-        q, new_cache["kp"], new_cache["vp"],
-        new_cache.get("kp_scale"), new_cache.get("vp_scale"),
-        block_table, idx + S,
-        fmt=_kv_fmt(cfg) if quant else None,
-        softcap=cfg.attn_logit_softcap,
-        sm_scale=1.0 / math.sqrt(hd))
+    with jax.named_scope("core"):
+        out = dispatch.paged_attend(
+            q, new_cache["kp"], new_cache["vp"],
+            new_cache.get("kp_scale"), new_cache.get("vp_scale"),
+            block_table, idx + S,
+            fmt=_kv_fmt(cfg) if quant else None,
+            softcap=cfg.attn_logit_softcap,
+            sm_scale=1.0 / math.sqrt(hd))
     return out.astype(q.dtype), new_cache
 
 
@@ -530,13 +536,16 @@ def mla_apply(
         qq, k, v = _qa(qq, cfg, qcfg), _qa(k, cfg, qcfg), _qa(v, cfg, qcfg)
         heads_div = h % model_axis_size() == 0
         qq, k, v = _shard_qkv(qq, k, v, heads_div)
-        out = flash_attention(qq, k, v, scale=1.0 / math.sqrt(nope + rpe))
+        with jax.named_scope("core"):
+            out = flash_attention(qq, k, v,
+                                  scale=1.0 / math.sqrt(nope + rpe))
         new_cache = None
     else:
         # absorbed decode folds kv_up into q/ctx einsums: dense view needed
-        out, new_cache = _mla_decode(q_nope, q_rope, c_kv, k_rope,
-                                     decoded_of(p["kv_up"], cfg, qcfg),
-                                     cache, cfg)
+        kv_up = decoded_of(p["kv_up"], cfg, qcfg)
+        with jax.named_scope("core"):
+            out, new_cache = _mla_decode(q_nope, q_rope, c_kv, k_rope,
+                                         kv_up, cache, cfg)
     out = out.reshape(B, S, h * vd)
     out = qeinsum("bse,ed->bsd", out, dense_of(p["wo"], cfg, qcfg), qcfg)
     return shard(out, "batch", "seq", "embed"), new_cache

@@ -160,22 +160,26 @@ def _block_apply(kind: str, bp, x, cfg: ArchConfig, qcfg, *, positions,
     aux = jnp.zeros((), jnp.float32)
     if kind in ("dense", "local", "global", "moe"):
         window, theta = _attn_kind_args(cfg, kind)
-        h = rms_norm(x, bp["ln1"], cfg.norm_eps)
-        if cfg.use_mla:
-            a, cache = attn_mod.mla_apply(bp["attn"], h, cfg, qcfg,
-                                          positions=positions, cache=cache)
-        else:
-            a, cache = attn_mod.attn_apply(bp["attn"], h, cfg, qcfg,
-                                           positions=positions, window=window,
-                                           theta=theta, cache=cache,
-                                           block_table=block_tables)
-        x = x + a
-        h = rms_norm(x, bp["ln2"], cfg.norm_eps)
-        if kind == "moe":
-            m, aux = moe_mod.moe_apply(bp["moe"], h, cfg, qcfg)
-        else:
-            m = mlp_apply(bp["mlp"], h, cfg, qcfg)
-        return x + m, cache, aux
+        with jax.named_scope("attention"):
+            h = rms_norm(x, bp["ln1"], cfg.norm_eps)
+            if cfg.use_mla:
+                a, cache = attn_mod.mla_apply(bp["attn"], h, cfg, qcfg,
+                                              positions=positions,
+                                              cache=cache)
+            else:
+                a, cache = attn_mod.attn_apply(
+                    bp["attn"], h, cfg, qcfg, positions=positions,
+                    window=window, theta=theta, cache=cache,
+                    block_table=block_tables)
+            x = x + a
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, bp["ln2"], cfg.norm_eps)
+            if kind == "moe":
+                m, aux = moe_mod.moe_apply(bp["moe"], h, cfg, qcfg)
+            else:
+                m = mlp_apply(bp["mlp"], h, cfg, qcfg)
+            x = x + m
+        return x, cache, aux
 
     if kind == "mamba":
         h = rms_norm(x, bp["ln"], cfg.norm_eps)
@@ -188,16 +192,20 @@ def _block_apply(kind: str, bp, x, cfg: ArchConfig, qcfg, *, positions,
 
     if kind == "shared_attn":
         sp = shared
-        h = rms_norm(x, bp["ln1"], cfg.norm_eps)
-        attn_p = dict(sp["attn"])
-        if "lora_a" in bp:
-            attn_p = _lora_qkv(attn_p, bp, h, cfg, qcfg)
-        a, cache = attn_mod.attn_apply(attn_p, h, cfg, qcfg,
-                                       positions=positions, cache=cache,
-                                       block_table=block_tables)
-        x = x + a
-        m = mlp_apply(sp["mlp"], rms_norm(x, bp["ln2"], cfg.norm_eps), cfg, qcfg)
-        return x + m, cache, aux
+        with jax.named_scope("attention"):
+            h = rms_norm(x, bp["ln1"], cfg.norm_eps)
+            attn_p = dict(sp["attn"])
+            if "lora_a" in bp:
+                attn_p = _lora_qkv(attn_p, bp, h, cfg, qcfg)
+            a, cache = attn_mod.attn_apply(attn_p, h, cfg, qcfg,
+                                           positions=positions, cache=cache,
+                                           block_table=block_tables)
+            x = x + a
+        with jax.named_scope("mlp"):
+            m = mlp_apply(sp["mlp"], rms_norm(x, bp["ln2"], cfg.norm_eps),
+                          cfg, qcfg)
+            x = x + m
+        return x, cache, aux
 
     raise ValueError(kind)
 
@@ -266,7 +274,8 @@ def forward(
     same page ids. Required iff ``caches`` contains paged layers.
     """
     prefix, n_periods, period = cfg.layer_pattern()
-    x = _embed(params, tokens, cfg, qcfg)
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, cfg, qcfg)
     if patches is not None:
         x = jnp.concatenate([patches.astype(x.dtype), x], axis=1)
     S = x.shape[1]
@@ -320,8 +329,9 @@ def forward(
         if caches is not None:
             new_caches["period"] = ys
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _logits(params, x, cfg, qcfg)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = _logits(params, x, cfg, qcfg)
     return ForwardOut(logits, (new_caches if caches is not None else None),
                       aux_total, x)
 
@@ -340,11 +350,11 @@ def lm_loss(params, batch: Dict[str, jax.Array], cfg: ArchConfig,
         logits = logits[:, n_patch:]   # text positions only
         hidden = hidden[:, n_patch:]
 
-    if cfg.num_codebooks:
-        B, S, K = labels.shape
-        logits = logits.reshape(B, S, K, cfg.vocab_size)
-    ce = _xent(logits, labels)
-    loss = ce + 0.01 * out.aux
+    with jax.named_scope("head"):
+        if cfg.num_codebooks:
+            B, S, K = labels.shape
+            logits = logits.reshape(B, S, K, cfg.vocab_size)
+        loss = _xent(logits, labels) + 0.01 * out.aux
 
     if cfg.mtp_depth and "mtp" in params:
         loss = loss + 0.3 * _mtp_loss(params, hidden, tokens, labels, cfg, qcfg)

@@ -20,7 +20,7 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-from repro.obs.spans import (CAT_ENGINE, CAT_REQUEST, SpanRing, request_tid)
+from repro.obs.spans import CAT_REQUEST, SpanRing, request_tid
 from repro.obs.timeline import StepTimeline
 
 __all__ = ["EngineObserver"]
@@ -81,8 +81,6 @@ class EngineObserver:
 
     def decode_step(self, t0: float, t1: float, *, emitted: int,
                     gauges: Optional[Dict[str, int]] = None) -> None:
-        self.spans.complete("decode_step", CAT_ENGINE, 0, t0, t1,
-                            {"emitted": emitted})
         self.timeline.record("decode", t0, t1, emitted=emitted,
                              **(gauges or {}))
 
@@ -97,8 +95,6 @@ class EngineObserver:
             self.spans.complete("spec", CAT_REQUEST, request_tid(rid),
                                 t0, t1, {"k": k, "accepted": acc,
                                          "emitted": emit})
-        self.spans.complete("spec_cycle", CAT_ENGINE, 0, t0, t1,
-                            {"k": k, "slots": len(rows)})
         self.timeline.record("spec", t0, t1, emitted=emitted,
                              drafted=k * len(rows), accepted=accepted,
                              **(gauges or {}))
@@ -111,24 +107,34 @@ class EngineObserver:
         out["span_dropped"] = self.spans.dropped
         return out
 
-    def time_breakdown(self, wall_s: Optional[float] = None
-                       ) -> Dict[str, float]:
-        """Walltime shares by phase. With ``wall_s`` (the run's total
-        wall), the uninstrumented remainder is attributed to host-side
-        scheduling/bookkeeping — the gap jit launches can't explain."""
+    def time_breakdown(self, phases: Dict[str, Dict[str, float]],
+                       wall_s: Optional[float] = None) -> Dict[str, float]:
+        """Where a run's time went. ``phases`` is the engine's
+        ``phase_stats()`` over the same run: ``wait_s`` is the host blocked
+        on the device (the summed ``*.wait`` spans) and ``host_s`` the rest
+        of ``engine.step``, host work no device result held up. With
+        ``wall_s`` (the run's total wall), ``outside_s`` is the time outside
+        ``engine.step`` (waiting for arrivals, the caller's loop) and the
+        shares are over ``wall_s``; else over the steps' total. The
+        timeline adds the walltime of prefill, decode and spec work."""
         s = self.timeline.summary()
+        step = phases.get("engine.step", {}).get("total_s", 0.0)
+        wait = sum(v["total_s"] for k, v in phases.items()
+                   if k.endswith(".wait"))
         out = {
             "prefill_s": s.get("prefill_time_s", 0.0),
             "decode_s": s.get("decode_time_s", 0.0),
             "spec_s": s.get("spec_time_s", 0.0),
+            "step_s": step,
+            "wait_s": wait,
+            "host_s": max(step - wait, 0.0),
         }
-        device = sum(out.values())
         if wall_s is not None:
             out["wall_s"] = wall_s
-            out["host_s"] = max(wall_s - device, 0.0)
-        total = wall_s if wall_s else device
+            out["outside_s"] = max(wall_s - step, 0.0)
+        total = wall_s if wall_s else step
         if total > 0:
-            for key in ("prefill", "decode", "spec", "host"):
+            for key in ("wait", "host", "outside"):
                 if f"{key}_s" in out:
                     out[f"{key}_share"] = round(out[f"{key}_s"] / total, 4)
         return out

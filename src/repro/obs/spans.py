@@ -17,15 +17,27 @@ Appends happen on the engine/driver thread while exports may run on the
 gateway's asyncio thread (``GET /obs/trace``), so the ring guards its
 deque with a lock — the lock is only ever taken when tracing is enabled,
 never on the disabled hot path.
+
+The engine's own phases (admission, prefill, page growth, the decode
+step's staging, launch and wait, token emission) go through
+:class:`Phases`, the one span primitive: each span is a
+``jax.profiler.TraceAnnotation`` named ``repro.<phase>`` (on the
+profiler's host line, the clock the device trace is aligned to), an
+always-on per-phase counter (count, total and longest seconds), and, when
+an observer is attached, a complete span on the engine lane of the ring —
+one clock read at each edge feeds all three.
 """
 from __future__ import annotations
 
 import json
 import threading
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional
 
-__all__ = ["SpanRing", "ENGINE_TID", "request_tid", "validate_chrome_trace"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["SpanRing", "ENGINE_TID", "request_tid", "validate_chrome_trace",
+           "Phases", "Span", "SPAN_PREFIX"]
 
 ENGINE_TID = 0
 
@@ -36,6 +48,9 @@ CAT_ENGINE = "engine"
 # the request phases the acceptance bar requires for every completed
 # request (spec spans additionally required when speculation ran)
 REQUEST_PHASES = ("queue", "prefill", "decode")
+
+# the profiler-trace name of a phase span is SPAN_PREFIX + its phase name
+SPAN_PREFIX = "repro."
 
 
 def request_tid(rid: int) -> int:
@@ -122,6 +137,76 @@ class SpanRing:
             json.dump(self.to_chrome(extra_events), f)
             f.write("\n")
         return path
+
+
+# ---------------------------------------------------------------------------
+# phase spans (the engine's step, on the profiler's clock)
+
+
+class Span:
+    """One phase interval; use through :meth:`Phases.span`. After the
+    with-block, ``t0`` and ``t1`` hold its edges on the owner's clock."""
+
+    __slots__ = ("_phases", "name", "args", "_ann", "t0", "t1")
+
+    def __init__(self, phases: "Phases", name: str, args: Dict[str, Any]):
+        self._phases = phases
+        self.name = name
+        self.args = args
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(SPAN_PREFIX + self.name, **self.args)
+        self._ann.__enter__()
+        self.t0 = self._phases.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = self._phases.clock()
+        self._ann.__exit__(*exc)
+        self._phases._close(self)
+        return False
+
+
+class Phases:
+    """Per-phase counters of one engine, and the span primitive feeding
+    them. ``clock`` gives seconds (the engine clock, so ring spans line up
+    with request spans); ``ring`` is the attached observer's span ring,
+    or None. Single-writer: the thread that steps the engine."""
+
+    def __init__(self, clock: Callable[[], float]):
+        self.clock = clock
+        self.ring: Optional[SpanRing] = None
+        self._rows: Dict[str, List[float]] = {}  # name -> [n, total, max]
+
+    def span(self, name: str, **args) -> Span:
+        """``with phases.span("engine.step"):`` — ``args`` (ints, floats,
+        strings) become the profiler event's stats and the ring span's
+        args."""
+        return Span(self, name, args)
+
+    def _close(self, sp: Span) -> None:
+        d = sp.t1 - sp.t0
+        row = self._rows.get(sp.name)
+        if row is None:
+            self._rows[sp.name] = [1, d, d]
+        else:
+            row[0] += 1
+            row[1] += d
+            if d > row[2]:
+                row[2] = d
+        if self.ring is not None:
+            self.ring.complete(SPAN_PREFIX + sp.name, CAT_ENGINE, ENGINE_TID,
+                               sp.t0, sp.t1, sp.args or None)
+
+    def snapshot(self) -> Dict[str, Dict[str, float]]:
+        """``{phase: {"count", "total_s", "max_s"}}`` since the last
+        :meth:`clear`."""
+        return {name: {"count": int(n), "total_s": t, "max_s": m}
+                for name, (n, t, m) in self._rows.items()}
+
+    def clear(self) -> None:
+        self._rows = {}
 
 
 # ---------------------------------------------------------------------------

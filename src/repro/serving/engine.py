@@ -86,6 +86,7 @@ from repro.distributed.sharding import serving_rules, shard_ctx
 from repro.kernels import dispatch
 from repro.models.common import ArchConfig
 from repro.models.model import forward, init_caches
+from repro.obs.spans import Phases
 from repro.optim.madam import MadamConfig
 from repro.server.sampling import sample_logits, sampling_rows, set_row
 from repro.serving.metrics import RequestMetrics, summarize
@@ -257,9 +258,10 @@ class Engine:
         self.token_sink: Optional[Callable[[int, Any], None]] = None
         self.finish_sink: Optional[
             Callable[[int, str, Optional[RequestState]], None]] = None
-        # observability (repro.obs.EngineObserver, DESIGN.md §13): every
-        # hot-path hook site is a single `is not None` check, so the
-        # default costs one attribute load per step — no allocation
+        # phase spans and counters (always on) and observability
+        # (repro.obs.EngineObserver, DESIGN.md §13): every observer hook
+        # site is a single `is not None` check
+        self.phases = Phases(self._now)
         self.observer = observer
         # identity of the loaded weights, surfaced by /health; serving
         # launchers stamp it (checkpoint path / smoke-init tag)
@@ -345,7 +347,9 @@ class Engine:
         self.aborted: List[RequestState] = []   # cancelled mid-flight
         self._run_sink: Optional[List[RequestMetrics]] = None
         self.decode_steps = 0
+        self.decode_rows = 0             # live rows summed over decode steps
         self.prefills = 0
+        self.phases.clear()
         self.admit_failures = 0          # requests that blew up in _admit
         self._admit_fail_streak = 0      # consecutive; trips the engine
         self.prefill_tokens = 0          # padded tokens actually prefilled
@@ -382,11 +386,25 @@ class Engine:
     # ------------------------------------------------------------------
     # observability (only touched when an observer is attached)
 
+    @property
+    def observer(self):
+        return self._observer
+
+    @observer.setter
+    def observer(self, observer) -> None:
+        self._observer = observer
+        self.phases.ring = observer.spans if observer is not None else None
+
     def attach_observer(self, observer) -> None:
         """Attach a ``repro.obs.EngineObserver`` (None detaches). Spans
         and timeline rows are stamped with the engine clock, so attach
         before (or at) the run whose events you want coherent."""
         self.observer = observer
+
+    def phase_stats(self) -> Dict[str, Dict[str, float]]:
+        """Per step phase (``engine.step``, ``engine.decode.wait``, ...):
+        count, total and longest seconds since the last ``reset()``."""
+        return self.phases.snapshot()
 
     def _obs_gauges(self) -> Dict[str, int]:
         """The step-timeline gauge row (allocator counts are O(1))."""
@@ -684,10 +702,11 @@ class Engine:
         """On-device sampler body (jitted standalone for prefill logits,
         inlined into the decode jit for the hot loop; ``step_offset``
         shifts the fold counter for the speculative verify positions)."""
-        return sample_logits(logits, samp,
-                             num_codebooks=self.cfg.num_codebooks,
-                             vocab_size=self.cfg.vocab_size,
-                             step_offset=step_offset)
+        with jax.named_scope("sample"):
+            return sample_logits(logits, samp,
+                                 num_codebooks=self.cfg.num_codebooks,
+                                 vocab_size=self.cfg.vocab_size,
+                                 step_offset=step_offset)
 
     def _samp_row(self, slot: int) -> Dict[str, jax.Array]:
         """Batch-1 view of one slot's sampling params (prefill sample)."""
@@ -861,94 +880,98 @@ class Engine:
     # admission / decode
 
     def _admit(self, rs: RequestState, clock,
-               resv: Optional[Dict[str, Any]] = None) -> None:
+               resv: Optional[Dict[str, Any]] = None):
+        """Prefill ``rs`` into its slot and emit its first token (or, for
+        a preempted request, recompute its evicted KV). Returns the
+        ``engine.prefill`` span."""
         req = rs.request
-        prompt = np.asarray(req.prompt, np.int32)
         g = len(rs.generated)
-        if g:
-            # resuming a preempted request (ondemand policy): recompute
-            # the evicted KV by prefilling the prompt plus every already
-            # delivered token except the last (which seeds decoding and,
-            # like any fresh prefill's sampled token, is never cached)
-            tail = np.asarray(rs.generated[:-1],
-                              np.int32).reshape((-1,) + prompt.shape[1:])
-            prompt = np.concatenate([prompt, tail])
-        plen = len(prompt)
-
-        if self._paged:
-            n_cached = resv["n_cached"]
-            held = resv["shared"] + resv["fresh"]
-            n_pages = resv["n_full"] + len(resv["fresh"])
-            bt = np.full((self._max_pages,), self._null_page, np.int32)
-            bt[:resv["n_full"]] = resv["shared"]
-            bt[resv["n_full"]:n_pages] = resv["fresh"]
-            # copy-on-write boundary: the shared page's content is copied
-            # onto its fresh twin inside the prefill jit; null -> null
-            # (a free self-copy of the sacrificial page) when absent
-            if resv["cow"] is not None:
-                cow_src, cow_dst = resv["cow"], int(bt[resv["n_full"]])
+        # resuming a preempted request (ondemand policy): recompute the
+        # evicted KV by prefilling the prompt plus every already delivered
+        # token except the last (which seeds decoding and, like any fresh
+        # prefill's sampled token, is never cached)
+        plen = req.prompt_len + max(g - 1, 0)
+        n_cached = resv["n_cached"] if self._paged else 0
+        bucket = self._bucket(plen - n_cached)
+        with self.phases.span("engine.prefill", rid=req.rid, bucket=bucket,
+                              n_cached=n_cached) as sp:
+            prompt = np.asarray(req.prompt, np.int32)
+            if g:
+                tail = np.asarray(rs.generated[:-1], np.int32).reshape(
+                    (-1,) + prompt.shape[1:])
+                prompt = np.concatenate([prompt, tail])
+            if self._paged:
+                held = resv["shared"] + resv["fresh"]
+                n_pages = resv["n_full"] + len(resv["fresh"])
+                bt = np.full((self._max_pages,), self._null_page, np.int32)
+                bt[:resv["n_full"]] = resv["shared"]
+                bt[resv["n_full"]:n_pages] = resv["fresh"]
+                # copy-on-write boundary: the shared page's content is copied
+                # onto its fresh twin inside the prefill jit; null -> null
+                # (a free self-copy of the sacrificial page) when absent
+                if resv["cow"] is not None:
+                    cow_src, cow_dst = resv["cow"], int(bt[resv["n_full"]])
+                else:
+                    cow_src = cow_dst = self._null_page
+                n_new = plen - n_cached
+                tokens = np.zeros((1, bucket) + prompt.shape[1:], np.int32)
+                tokens[0, :n_new] = prompt[n_cached:]
+                with self._ctx():
+                    logits, self.caches = self._prefill_fn(
+                        self.params, self.caches, self._put(tokens),
+                        self._put(n_new, jnp.int32),
+                        self._put(n_cached, jnp.int32),
+                        self._put(plen, jnp.int32),
+                        self._put(bt), self._put(cow_src, jnp.int32),
+                        self._put(cow_dst, jnp.int32),
+                        self._put(rs.slot, jnp.int32))
+                if resv["cow"] is not None:  # content copied; drop the hold
+                    self.allocator.release([resv["cow"]])
+                    resv["cow"] = None  # a later unwind must not re-release
+                if self._prefix_ok:  # publish this prompt's full pages
+                    # keys cover the *original* prompt only — resumed tokens
+                    # are generated content, never prefix-cache material
+                    for i, key in enumerate(resv["keys"]):
+                        self.allocator.register(key, int(bt[i]))
+                self._block_tables[rs.slot] = bt
+                self._slot_pages[rs.slot] = held
+                if n_cached:
+                    self.prefix_hits += 1
+                    self.prefix_reused_tokens += n_cached
             else:
-                cow_src = cow_dst = self._null_page
-            n_new = plen - n_cached
-            bucket = self._bucket(n_new)
-            tokens = np.zeros((1, bucket) + prompt.shape[1:], np.int32)
-            tokens[0, :n_new] = prompt[n_cached:]
-            with self._ctx():
-                logits, self.caches = self._prefill_fn(
-                    self.params, self.caches, self._put(tokens),
-                    self._put(n_new, jnp.int32),
-                    self._put(n_cached, jnp.int32),
-                    self._put(plen, jnp.int32),
-                    self._put(bt), self._put(cow_src, jnp.int32),
-                    self._put(cow_dst, jnp.int32),
-                    self._put(rs.slot, jnp.int32))
-            if resv["cow"] is not None:  # content copied; drop the hold
-                self.allocator.release([resv["cow"]])
-                resv["cow"] = None  # a later unwind must not re-release
-            if self._prefix_ok:  # publish this prompt's full pages
-                # keys cover the *original* prompt only — resumed tokens
-                # are generated content, never prefix-cache material
-                for i, key in enumerate(resv["keys"]):
-                    self.allocator.register(key, int(bt[i]))
-            self._block_tables[rs.slot] = bt
-            self._slot_pages[rs.slot] = held
-            if n_cached:
-                self.prefix_hits += 1
-                self.prefix_reused_tokens += n_cached
-        else:
-            bucket = self._bucket(plen)
-            tokens = np.zeros((1, bucket) + prompt.shape[1:], np.int32)
-            tokens[0, :plen] = prompt
-            with self._ctx():
-                logits, self.caches = self._prefill_fn(
-                    self.params, self.caches, self._mini_template,
-                    self._put(tokens), self._put(plen, jnp.int32),
-                    self._put(rs.slot, jnp.int32))
+                tokens = np.zeros((1, bucket) + prompt.shape[1:], np.int32)
+                tokens[0, :plen] = prompt
+                with self._ctx():
+                    logits, self.caches = self._prefill_fn(
+                        self.params, self.caches, self._mini_template,
+                        self._put(tokens), self._put(plen, jnp.int32),
+                        self._put(rs.slot, jnp.int32))
 
-        set_row(self._samp, rs.slot, req.sampling)  # sample event 0
-        if g:
-            # every emitted token was already delivered; the last one
-            # seeds decoding and the sampling chain resumes at event g —
-            # same seed, same counter, so no token is ever re-drawn
-            # (logits can still move by an ULP vs the unpreempted run:
-            # per-tensor activation scales couple co-resident rows)
-            tok = np.asarray(rs.generated[-1], np.int32)
-            self._samp["step"][rs.slot] = g
-        else:
-            with self._ctx():
-                tok = np.asarray(
-                    self._sample_fn(logits, self._samp_row(rs.slot)))[0]
-            self._samp["step"][rs.slot] = 1
-        self.prefills += 1
-        self.prefill_tokens += bucket
-        self._slot_len[rs.slot] = plen
-        self._last_tok[rs.slot] = tok
-        if not g:
-            rs.generated.append(tok.tolist() if tok.ndim else int(tok))
-            rs.t_first_token = clock()
-            if self.token_sink is not None:
-                self.token_sink(req.rid, rs.generated[-1])
-        self._maybe_finish(rs, clock)
+            set_row(self._samp, rs.slot, req.sampling)  # sample event 0
+            if g:
+                # every emitted token was already delivered; the last one
+                # seeds decoding and the sampling chain resumes at event g —
+                # same seed, same counter, so no token is ever re-drawn
+                # (logits can still move by an ULP vs the unpreempted run:
+                # per-tensor activation scales couple co-resident rows)
+                tok = np.asarray(rs.generated[-1], np.int32)
+                self._samp["step"][rs.slot] = g
+            else:
+                with self._ctx(), self.phases.span("engine.prefill.wait"):
+                    tok = np.asarray(
+                        self._sample_fn(logits, self._samp_row(rs.slot)))[0]
+                self._samp["step"][rs.slot] = 1
+            self.prefills += 1
+            self.prefill_tokens += bucket
+            self._slot_len[rs.slot] = plen
+            self._last_tok[rs.slot] = tok
+            if not g:
+                rs.generated.append(tok.tolist() if tok.ndim else int(tok))
+                rs.t_first_token = clock()
+                if self.token_sink is not None:
+                    self.token_sink(req.rid, rs.generated[-1])
+            self._maybe_finish(rs, clock)
+        return sp
 
     def _maybe_finish(self, rs: RequestState, clock) -> None:
         # the cursor names the *next* write position: the slot is out of
@@ -1091,67 +1114,69 @@ class Engine:
         per-tensor activation-scale ULP caveat)."""
         bits, _ = self._spec_arm
         obs = self.observer
-        t0 = time.monotonic()
-        t_s0 = self._now() if obs is not None else 0.0
-        pos0 = self._slot_len.copy()
-        batch_bt = self._put(self._block_tables) if self._paged else None
-        samp = {kk: self._put(v) for kk, v in self._samp.items()}
-        with self._ctx():
-            s_dev, acc_dev, m_dev, self.caches = self._spec_fn(
-                self._draft_params(bits), self.params, self.caches,
-                self._put(self._last_tok), self._put(pos0, jnp.int32),
-                samp, batch_bt, k=k)
-        s = np.array(s_dev)
-        acc = np.array(acc_dev)
-        m = np.array(m_dev).astype(np.int64)
-        self._admit_fail_streak = 0
-        self.spec_cycles += 1
-        self.spec_draft_steps += k
-        self.spec_verify_steps += 1
-        # per-row mirrors advance by the emitted count — idle rows too
-        # (they drafted greedily into dead rows, exactly as the baseline
-        # step advances every row by 1)
-        self._slot_len = pos0 + m
-        self._samp["step"] += m.astype(np.int32)
-        self._last_tok = s[np.arange(self.num_slots), m - 1].astype(np.int32)
-        emitted_total = 0
-        per_class: Dict[str, Any] = {}
-        obs_rows: Optional[List] = [] if obs is not None else None
-        for slot, rs in list(self.scheduler.running.items()):
-            a = int(acc[slot])
-            if obs_rows is not None:
-                obs_rows.append((rs.request.rid, a, int(m[slot])))
-            self.spec_drafted += k
-            self.spec_accepted += a
-            rs.spec_cycles += 1
-            rs.spec_drafted += k
-            rs.spec_accepted += a
-            if self._tuner is not None:
-                cls = request_class(rs.request)
-                ca, cd = per_class.get(cls, (0, 0))
-                per_class[cls] = (ca + a, cd + k)
-            for j in range(int(m[slot])):
-                rs.generated.append(int(s[slot, j]))
-                emitted_total += 1
-                if self.token_sink is not None:
-                    self.token_sink(rs.request.rid, rs.generated[-1])
-                if rs.done:
-                    # a stop/budget transition inside the accepted run is
-                    # terminal — the run's later tokens were never part of
-                    # the baseline stream and are dropped unemitted (the
-                    # cursor overshoot is moot: the slot releases below)
-                    break
-            self._maybe_finish(rs, clock)
-            if self._ondemand and self.scheduler.running.get(slot) is rs:
-                self._trim_overshoot(rs)
-        self.spec_emitted += emitted_total
+        with self.phases.span("engine.spec", k=k) as sp:
+            pos0 = self._slot_len.copy()
+            batch_bt = self._put(self._block_tables) if self._paged else None
+            samp = {kk: self._put(v) for kk, v in self._samp.items()}
+            with self._ctx():
+                s_dev, acc_dev, m_dev, self.caches = self._spec_fn(
+                    self._draft_params(bits), self.params, self.caches,
+                    self._put(self._last_tok), self._put(pos0, jnp.int32),
+                    samp, batch_bt, k=k)
+            with self.phases.span("engine.spec.wait"):
+                s = np.array(s_dev)
+                acc = np.array(acc_dev)
+                m = np.array(m_dev).astype(np.int64)
+            self._admit_fail_streak = 0
+            self.spec_cycles += 1
+            self.spec_draft_steps += k
+            self.spec_verify_steps += 1
+            # per-row mirrors advance by the emitted count — idle rows too
+            # (they drafted greedily into dead rows, exactly as the
+            # baseline step advances every row by 1)
+            self._slot_len = pos0 + m
+            self._samp["step"] += m.astype(np.int32)
+            self._last_tok = s[np.arange(self.num_slots),
+                               m - 1].astype(np.int32)
+            emitted_total = 0
+            per_class: Dict[str, Any] = {}
+            obs_rows: Optional[List] = [] if obs is not None else None
+            for slot, rs in list(self.scheduler.running.items()):
+                a = int(acc[slot])
+                if obs_rows is not None:
+                    obs_rows.append((rs.request.rid, a, int(m[slot])))
+                self.spec_drafted += k
+                self.spec_accepted += a
+                rs.spec_cycles += 1
+                rs.spec_drafted += k
+                rs.spec_accepted += a
+                if self._tuner is not None:
+                    cls = request_class(rs.request)
+                    ca, cd = per_class.get(cls, (0, 0))
+                    per_class[cls] = (ca + a, cd + k)
+                for j in range(int(m[slot])):
+                    rs.generated.append(int(s[slot, j]))
+                    emitted_total += 1
+                    if self.token_sink is not None:
+                        self.token_sink(rs.request.rid, rs.generated[-1])
+                    if rs.done:
+                        # a stop/budget transition inside the accepted run
+                        # is terminal — the run's later tokens were never
+                        # part of the baseline stream and are dropped
+                        # unemitted (the cursor overshoot is moot: the
+                        # slot releases below)
+                        break
+                self._maybe_finish(rs, clock)
+                if self._ondemand and self.scheduler.running.get(slot) is rs:
+                    self._trim_overshoot(rs)
+            self.spec_emitted += emitted_total
         if obs is not None:
-            obs.spec_cycle(t_s0, self._now(), k=k, rows=obs_rows,
+            obs.spec_cycle(sp.t0, sp.t1, k=k, rows=obs_rows,
                            emitted=emitted_total,
                            gauges=self._obs_gauges())
         if self._tuner is not None:
             self._tuner.observe(self._spec_arm, emitted_total,
-                                time.monotonic() - t0, per_class)
+                                sp.t1 - sp.t0, per_class)
             self._spec_arm = self._tuner.propose()
 
     @property
@@ -1188,53 +1213,64 @@ class Engine:
         With an explicit ``now`` (simulated-time replay) every timestamp
         this step produces uses that value, so TTFT/latency stay in the
         caller's clock; otherwise the engine's monotonic clock is read at
-        each event."""
+        each event.
+
+        Each phase runs inside a span (``self.phases``) that is a child of
+        ``engine.step``: host time under ``engine.step`` alone is time no
+        phase claims."""
+        with self.phases.span("engine.step"):
+            return self._step(now)
+
+    def _step(self, now: Optional[float]) -> bool:
         clock = self._now if now is None else (lambda: now)
         obs = self.observer
+        span = self.phases.span
         while self.scheduler.has_free():
             req = self.queue.pop_ready(clock())
             if req is None:
                 break
-            resv = None
-            resume = self._preempted.get(req.rid) if self._paged else None
-            if self._paged:
-                n_tok = req.prompt_len
+            with span("engine.admit", rid=req.rid,
+                      prompt_len=req.prompt_len):
+                resv = None
+                resume = self._preempted.get(req.rid) if self._paged \
+                    else None
+                if self._paged:
+                    n_tok = req.prompt_len
+                    if resume is not None:
+                        n_tok += len(resume.generated) - 1
+                    try:
+                        resv = self._reserve_pages(req, n_tok)
+                    except Exception:
+                        # a prompt the reservation can't even hash
+                        # (slipped past validate()) fails alone, before
+                        # slot binding; archive an "error" state (slot
+                        # -1: never bound) so offline callers' accounting
+                        # still balances
+                        rs = RequestState(request=req, slot=-1,
+                                          t_admit=clock())
+                        rs.t_finish = clock()
+                        self._archive_error(rs)
+                        if self._admit_fail_streak >= ADMIT_FAIL_TRIP:
+                            raise
+                        continue
+                    if resv is None:  # pool exhausted: wait for a release
+                        self.queue.requeue(req)
+                        break
+                rs = self.scheduler.admit(req, clock())
                 if resume is not None:
-                    n_tok += len(resume.generated) - 1
-                try:
-                    resv = self._reserve_pages(req, n_tok)
-                except Exception:
-                    # a prompt the reservation can't even hash (slipped
-                    # past validate()) fails alone, before slot binding;
-                    # archive an "error" state (slot -1: never bound) so
-                    # offline callers' accounting still balances
-                    rs = RequestState(request=req, slot=-1,
-                                      t_admit=clock())
-                    rs.t_finish = clock()
-                    self._archive_error(rs)
-                    if self._admit_fail_streak >= ADMIT_FAIL_TRIP:
-                        raise
-                    continue
-                if resv is None:  # pool exhausted: wait for a release
-                    self.queue.requeue(req)
-                    break
-            rs = self.scheduler.admit(req, clock())
-            if resume is not None:
-                # continuity across preemption: same token list (the
-                # resume prefill keys off it) and original timestamps,
-                # so TTFT/latency metrics span the whole request
-                del self._preempted[req.rid]
-                rs.generated = resume.generated
-                rs.t_admit = resume.t_admit
-                rs.t_first_token = resume.t_first_token
-            t_p0 = self._now() if obs is not None else 0.0
+                    # continuity across preemption: same token list (the
+                    # resume prefill keys off it) and original timestamps,
+                    # so TTFT/latency metrics span the whole request
+                    del self._preempted[req.rid]
+                    rs.generated = resume.generated
+                    rs.t_admit = resume.t_admit
+                    rs.t_first_token = resume.t_first_token
             try:
-                self._admit(rs, clock, resv)
+                sp = self._admit(rs, clock, resv)
                 self._admit_fail_streak = 0
                 if obs is not None:
                     obs.admitted(rs, resumed=resume is not None)
-                    obs.prefill(rs, t_p0, self._now(),
-                                gauges=self._obs_gauges())
+                    obs.prefill(rs, sp.t0, sp.t1, gauges=self._obs_gauges())
             except Exception:
                 # a request that blows up inside admission (a shape that
                 # slipped past validate(), a prefill-time failure) must
@@ -1267,7 +1303,8 @@ class Engine:
                     raise
         spec_k = self._spec_arm[1] if self.spec is not None else 0
         if self._ondemand:
-            self._grow_decode_pages(lookahead=max(spec_k, 1))
+            with span("engine.grow_pages"):
+                self._grow_decode_pages(lookahead=max(spec_k, 1))
         if not self.scheduler.running:
             return False
 
@@ -1279,14 +1316,15 @@ class Engine:
             # pressure: advance everyone one plain token this step
             self.spec_fallbacks += 1
 
-        t_d0 = self._now() if obs is not None else 0.0
-        tokens = self._last_tok[:, None]  # (B, 1[, K])
-        pos = self._put(self._slot_len, jnp.int32)
-        batch = {"tokens": self._put(tokens)}
-        if self._paged:
-            batch["block_tables"] = self._put(self._block_tables)
-        samp = {k: self._put(v) for k, v in self._samp.items()}
-        with self._ctx():
+        with span("engine.decode.stage",
+                  rows=len(self.scheduler.running)) as stage:
+            tokens = self._last_tok[:, None]  # (B, 1[, K])
+            pos = self._put(self._slot_len, jnp.int32)
+            batch = {"tokens": self._put(tokens)}
+            if self._paged:
+                batch["block_tables"] = self._put(self._block_tables)
+            samp = {k: self._put(v) for k, v in self._samp.items()}
+        with span("engine.decode.launch"), self._ctx():
             toks_dev, self.caches = self._decode_fn(
                 self.params, self.caches, batch, pos, samp)
         # a successful decode proves the engine itself is healthy, so
@@ -1296,20 +1334,23 @@ class Engine:
         self._admit_fail_streak = 0
         # token ids only — logits stay on device (np.asarray of a jax
         # array is a read-only view; copy so _last_tok stays writable)
-        toks = np.array(toks_dev)
-        self.decode_steps += 1
-        self._slot_len += 1  # every row's in-graph cursor advanced by 1
-        self._samp["step"] += 1
-        self._last_tok = toks
-        live = list(self.scheduler.running.items())
-        for slot, rs in live:
-            t = toks[slot]
-            rs.generated.append(t.tolist() if t.ndim else int(t))
-            if self.token_sink is not None:
-                self.token_sink(rs.request.rid, rs.generated[-1])
-            self._maybe_finish(rs, clock)
+        with span("engine.decode.wait"):
+            toks = np.array(toks_dev)
+        with span("engine.emit") as emit:
+            self.decode_steps += 1
+            self._slot_len += 1  # every row's in-graph cursor advanced by 1
+            self._samp["step"] += 1
+            self._last_tok = toks
+            live = list(self.scheduler.running.items())
+            self.decode_rows += len(live)
+            for slot, rs in live:
+                t = toks[slot]
+                rs.generated.append(t.tolist() if t.ndim else int(t))
+                if self.token_sink is not None:
+                    self.token_sink(rs.request.rid, rs.generated[-1])
+                self._maybe_finish(rs, clock)
         if obs is not None:
-            obs.decode_step(t_d0, self._now(), emitted=len(live),
+            obs.decode_step(stage.t0, emit.t1, emitted=len(live),
                             gauges=self._obs_gauges())
         return True
 

@@ -95,8 +95,9 @@ def build_train_step(
             # CSEs the scale/log2 work with the encode itself (measuring
             # the quantized output instead would double the reductions and
             # see an already-clamped tensor)
-            enc = grad_encode_stats(grads, qcfg) if numerics else {}
-            return loss, quantize_grads(grads, qcfg), enc
+            with jax.named_scope("grad_quantize"):
+                enc = grad_encode_stats(grads, qcfg) if numerics else {}
+                return loss, quantize_grads(grads, qcfg), enc
 
         # zeros fold to a broadcast constant inside jit: the carriers cost
         # no HBM; only the gradient outputs are dense
@@ -127,12 +128,14 @@ def build_train_step(
             enc_stats = jax.tree.map(lambda x: jnp.mean(x, axis=0),
                                      enc_stack)
 
-        if numerics:
-            new_params, new_opt, upd_stats = opt_update(
-                grads, state.opt, state.params, with_stats=True,
-                requant_fmt=fwd_fmt)
-        else:
-            new_params, new_opt = opt_update(grads, state.opt, state.params)
+        with jax.named_scope("optimizer"):
+            if numerics:
+                new_params, new_opt, upd_stats = opt_update(
+                    grads, state.opt, state.params, with_stats=True,
+                    requant_fmt=fwd_fmt)
+            else:
+                new_params, new_opt = opt_update(grads, state.opt,
+                                                 state.params)
         gnorm = jnp.sqrt(sum(
             jnp.sum(jnp.square(g.astype(jnp.float32)))
             for g in jax.tree.leaves(grads)))

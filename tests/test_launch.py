@@ -104,3 +104,14 @@ def test_require_exact_rounding_adds_the_flag_once(monkeypatch):
     mesh.require_exact_rounding()
     assert os.environ["XLA_FLAGS"].split() == [
         "--xla_dump_to=x", mesh.EXACT_ROUNDING_FLAG]
+
+
+def test_serve_cli_jax_profile_writes_the_engine_spans(monkeypatch, cli_env,
+                                                       tmp_path):
+    from jax.profiler import ProfileData
+    argv = SERVE + ["--jax-profile", str(tmp_path / "profile")]
+    assert _exit_status(serve.main, argv, monkeypatch) == 0
+    path = next((tmp_path / "profile").rglob("*.xplane.pb"))
+    names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events}
+    assert {"repro.engine.step", "repro.engine.decode.wait"} <= names

@@ -1,7 +1,7 @@
-"""Observability layer: span ring + Chrome trace contract, step
-timeline, Prometheus exposition round trip, kernel-time attribution, and
-the engine/driver integration (spans for every completed request, torn-
-read-free /metrics under scrape concurrency)."""
+"""Observability layer: span ring + Chrome trace contract, phase spans,
+step timeline, Prometheus exposition round trip, and the engine/driver
+integration (spans for every completed request, torn-read-free /metrics
+under scrape concurrency)."""
 import json
 import threading
 
@@ -10,10 +10,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.obs import (EngineObserver, Histogram, SpanRing, StepTimeline,
-                       kernel_stats, parse_prometheus_text,
+from repro.obs import (EngineObserver, Histogram, Phases, SpanRing,
+                       StepTimeline, parse_prometheus_text,
                        render_prometheus, validate_chrome_trace)
-from repro.obs.spans import CAT_ENGINE, CAT_REQUEST, request_tid
+from repro.obs.spans import CAT_ENGINE, CAT_REQUEST, ENGINE_TID, request_tid
 from repro.serving import Engine, synthetic_trace
 
 
@@ -168,35 +168,32 @@ def test_prometheus_parser_rejects_bad_text():
 
 
 # ---------------------------------------------------------------------------
-# kernel-time attribution
+# phase spans
 
 
-def test_kernel_stats_traces_vs_calls():
-    from repro.kernels import dispatch
-    from repro.core.lns import LNSFormat
-
-    fmt = LNSFormat(bits=8, gamma=8)
-    x = jnp.asarray(np.linspace(-1, 1, 32, dtype=np.float32).reshape(4, 8))
-    try:
-        stats = kernel_stats.enable(block_every=1)
-        # eager: timed call (kwarg forwarding must not collide with the
-        # positional-only observe() parameters)
-        dispatch.encode_pack(x, fmt)
-        # jit: first call traces (counted as a trace, not timed), the
-        # cached second call never re-enters python
-        jitted = jax.jit(lambda a: dispatch.encode_pack(a, fmt)[0])
-        jitted(x)
-        jitted(x)
-        snap = kernel_stats.get()
-        row = next(v for k, v in snap.items() if v["op"] == "encode_pack")
-        assert row["calls"] == 1 and row["traces"] == 1
-        assert row["bits"] == 8
-        assert row["time_s"] >= 0.0
-        assert row["blocked_calls"] == 1  # block_every=1 samples every call
-    finally:
-        kernel_stats.disable()
-    assert kernel_stats.active() is None
-    assert kernel_stats.get() == {}
+def test_phases_count_and_record_on_the_engine_lane():
+    now = [0.0]
+    phases = Phases(lambda: now[0])
+    for dur in (0.25, 0.5):
+        with phases.span("engine.step"):
+            with phases.span("engine.decode.wait", rows=3) as sp:
+                now[0] += dur
+            now[0] += 0.125
+    assert (sp.t0, sp.t1) == (0.375, 0.875)
+    snap = phases.snapshot()
+    assert snap["engine.decode.wait"] == {"count": 2, "total_s": 0.75,
+                                          "max_s": 0.5}
+    assert snap["engine.step"]["total_s"] == pytest.approx(1.0)
+    assert snap["engine.step"]["max_s"] == pytest.approx(0.625)
+    # with a ring attached, the same intervals land on the engine lane
+    ring = SpanRing(8)
+    phases.ring = ring
+    with phases.span("engine.emit", rows=2):
+        now[0] += 1.0
+    assert ring.snapshot() == [("repro.engine.emit", CAT_ENGINE, ENGINE_TID,
+                                1.0, 1.0, {"rows": 2})]
+    phases.clear()
+    assert phases.snapshot() == {}
 
 
 # ---------------------------------------------------------------------------
@@ -231,12 +228,24 @@ def test_engine_emits_spans_per_completed_request(obs_run, tmp_path):
     s = obs.summary()
     assert s["prefill_steps"] >= 3
     assert s["spec_steps"] == eng.spec_cycles
-    bd = obs.time_breakdown(agg["wall_s"])
+    phases = eng.phase_stats()
+    bd = obs.time_breakdown(phases, agg["wall_s"])
     assert bd["wall_s"] == agg["wall_s"]
-    assert 0.0 <= bd["host_share"] <= 1.0
-    shares = sum(bd[k] for k in ("prefill_share", "decode_share",
-                                 "spec_share", "host_share"))
+    # the device wait is the summed *.wait spans, the host's share the
+    # rest of engine.step, and the three shares split the run's wall
+    assert phases["engine.spec.wait"]["count"] == eng.spec_cycles
+    assert bd["wait_s"] == pytest.approx(sum(
+        v["total_s"] for k, v in phases.items() if k.endswith(".wait")))
+    assert bd["host_s"] == pytest.approx(
+        phases["engine.step"]["total_s"] - bd["wait_s"])
+    assert bd["wait_s"] > 0 and bd["host_s"] > 0
+    shares = sum(bd[k] for k in ("wait_share", "host_share",
+                                 "outside_share"))
     assert shares == pytest.approx(1.0, abs=0.01)
+    # the observer's engine lane carries the phase spans
+    lane = {ev[0] for ev in obs.spans.snapshot() if ev[2] == ENGINE_TID}
+    assert {"repro.engine.step", "repro.engine.spec",
+            "repro.engine.prefill"} <= lane
 
 
 def test_engine_disabled_observer_is_default(smoke_serving_setup):

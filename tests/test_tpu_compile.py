@@ -12,6 +12,7 @@ each pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -155,3 +156,94 @@ def test_dispatched_kernels_compile_on_four_chip_mesh(topo):
     with shard_ctx(mesh):
         hlo = jax.jit(step).lower(*args).compile().as_text()
     assert hlo.count("tpu_custom_call") >= 3
+
+
+# the model's and the step builders' top-level named_scopes; "core" and
+# "kv_write" nest inside "attention"
+TOP_SCOPES = ("embed", "attention", "mlp", "head", "grad_quantize",
+              "optimizer", "sample")
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=.*?\s([a-z][\w\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_WRAPPED = re.compile(r"^[\w\-]*\((.*)\)$")
+
+
+def _scope_path(op_name):
+    """``op_name``'s components with transform wrappers removed:
+    ``transpose(jvp(attention))`` -> ``attention``."""
+    out = []
+    for part in op_name.split("/"):
+        m = _WRAPPED.match(part)
+        while m:
+            part = m.group(1)
+            m = _WRAPPED.match(part)
+        out.append(part)
+    return out
+
+
+def _instructions(hlo_text):
+    """``(opcode, op_name, is_mosaic_call)`` of every compiled
+    instruction."""
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m:
+            name = _OP_NAME.search(line)
+            yield (m.group(1), name.group(1) if name else "",
+                   'custom_call_target="tpu_custom_call"' in line)
+
+
+def _placed(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("step", ["train", "decode"])
+def test_step_ops_sit_under_one_top_level_scope(one_chip,
+                                                 smoke_serving_setup, step):
+    """The compiled train step and the engine's decode step at the smoke
+    config: every matmul (``dot``/``convolution``, fused or not) and every
+    Mosaic kernel call carries exactly one of the model's top-level
+    ``named_scope``s in its ``op_name``, transforms (``jvp``,
+    ``transpose``) unwrapped."""
+    from repro.kernels import dispatch
+    from repro.serving import Engine
+    from repro.training.steps import build_train_step, init_train_state
+
+    cfg, qcfg, mcfg, params = smoke_serving_setup
+    if step == "train":
+        state = jax.eval_shape(
+            lambda: init_train_state(jax.random.PRNGKey(0), cfg, mcfg))
+        batch = {k: jax.ShapeDtypeStruct((2, 16), jnp.int32)
+                 for k in ("tokens", "labels")}
+        fn, args = jax.jit(build_train_step(cfg, qcfg, mcfg)), (state, batch)
+        want = {"embed", "attention", "attention/core", "mlp", "head",
+                "grad_quantize", "optimizer"}
+    else:
+        eng = Engine(cfg, qcfg, mcfg, params, num_slots=4, max_len=64,
+                     page_size=16)
+        batch = {"tokens": eng._last_tok[:, None],
+                 "block_tables": eng._block_tables}
+        fn, args = eng._decode_fn, (eng.params, eng.caches, batch,
+                                    eng._slot_len.astype("int32"),
+                                    dict(eng._samp))
+        want = {"embed", "attention", "attention/core", "attention/kv_write",
+                "mlp", "head", "sample"}
+    with dispatch.configured(backend="pallas", interpret=False):
+        text = fn.lower(*_placed(args, one_chip)).compile().as_text()
+    seen, kernels = set(), 0
+    for opcode, op_name, custom in _instructions(text):
+        # XLA joins the op names of merged ops with ";"
+        paths = [_scope_path(op) for op in op_name.split(";")]
+        tops = [[p for p in path if p in TOP_SCOPES] for path in paths]
+        assert all(len(t) <= 1 for t in tops), op_name
+        if opcode in ("dot", "convolution") or custom:
+            assert len({s for t in tops for s in t}) == 1, op_name
+            kernels += custom
+        for path, top in zip(paths, tops):
+            if top:
+                sub = [p for p in ("core", "kv_write")
+                       if top == ["attention"] and p in path]
+                seen.add("/".join(top + sub[:1]))
+                break
+    assert kernels > 0
+    assert want <= seen

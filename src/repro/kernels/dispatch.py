@@ -1,7 +1,9 @@
 """Kernel backend registry: one dispatch point for the LNS hot paths.
 
 Every production consumer of the packed-LNS datapath (``qeinsum`` weight
-GEMMs, the Madam update, activation encode) routes through this module
+GEMMs, the Madam update, activation encode) and of the attention kernels
+(``paged_attend`` for serving, ``flash_attend`` for training) routes
+through this module
 instead of importing a kernel directly (DESIGN.md §4). Two backends:
 
 * ``"pallas"``    — the Pallas TPU kernels (compiled Mosaic on real TPUs,
@@ -60,6 +62,7 @@ __all__ = [
     "requant_pack",
     "madam_step",
     "paged_attend",
+    "flash_attend",
     "fused_sample",
 ]
 
@@ -399,6 +402,44 @@ def paged_attend(q: jax.Array, kp: jax.Array, vp: jax.Array,
         return jax.lax.with_sharding_constraint(
             out, NamedSharding(current_mesh(), P()))
     return _on_mesh(impl, *args) if use_pallas else impl(*args)
+
+
+def flash_attend(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                 scale: Optional[float] = None,
+                 window: Optional[int] = None,
+                 softcap: Optional[float] = None,
+                 backend: Optional[str] = None,
+                 interpret: Optional[bool] = None) -> jax.Array:
+    """Causal (optionally windowed, soft-capped) attention for training
+    and dense prefill: ``q (B, S, H, D)`` over ``k (B, Skv, H, D)``,
+    ``v (B, Skv, H, Dv)`` with KV heads already repeated -> ``(B, S, H,
+    Dv)`` in q's dtype.
+
+    The fused Pallas kernel (``kernels/flash_attend.py``, forward and
+    backward) runs where the backend resolves to ``"pallas"``, ``S ==
+    Skv``, the kernel tiles the shape (``flash_attend.supported``) and the
+    heads are whole on each device or sharded over the mesh's ``model``
+    axis (each shard attends its own heads). Everything else — the
+    sequence-parallel layout for head counts the model axis does not
+    divide, shapes the kernel does not tile — takes the jnp oracle,
+    ``models.attention.flash_attention``.
+    """
+    from repro.distributed.sharding import model_axis_size, spec_for
+    from repro.kernels import flash_attend as fa
+    from repro.models.attention import flash_attention
+    S, H, D = q.shape[1], q.shape[2], q.shape[3]
+    if (resolve_backend(backend) == "pallas" and k.shape[1] == S
+            and fa.supported(S, D, v.shape[-1])
+            and H % model_axis_size() == 0):
+        interp = resolve_interpret(interpret)
+        heads = spec_for(("batch", "seq", "act_heads", None))
+        return _on_mesh(
+            lambda q, k, v: fa.flash_attend(q, k, v, scale=scale,
+                                            window=window, softcap=softcap,
+                                            interpret=interp),
+            q, k, v, in_specs=(heads,) * 3, out_specs=heads)
+    return flash_attention(q, k, v, window=window, softcap=softcap,
+                           scale=scale)
 
 
 def fused_sample(logits: jax.Array, gumbel: Optional[jax.Array],

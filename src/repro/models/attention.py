@@ -1,9 +1,12 @@
 """Attention: GQA (± bias / qk-norm / sliding window / softcap) and MLA.
 
-Training/prefill use a flash-style attention (lax.scan over KV blocks with
-an online softmax — logits never materialize beyond one (B,H,Sq,blk) tile),
-sharded either by heads (when num_heads divides the model axis) or by query
-sequence (sequence-parallel fallback for head counts like 40/24/9).
+Training/prefill attend through ``dispatch.flash_attend``: the fused Pallas
+kernel (``kernels/flash_attend.py``) where it tiles the shape, else the jnp
+oracle here, ``flash_attention`` (lax.scan over KV blocks with an online
+softmax — logits never materialize beyond one (B,H,Sq,blk) tile). Heads
+are sharded when num_heads divides the model axis; otherwise the query
+sequence is (sequence-parallel fallback for head counts like 40/24/9),
+which only the oracle serves.
 
 Decode attends one query against the full cache with plain softmax; the
 cache's sequence axis is sharded over the model axis (split-KV
@@ -25,6 +28,7 @@ import jax.numpy as jnp
 from repro.core.lns import LNSFormat
 from repro.core.quantizer import QuantConfig, cot_boundary, qeinsum, ste_quantize
 from repro.distributed.sharding import current_mesh, model_axis_size, shard
+from repro.kernels import dispatch
 from repro.models.common import ArchConfig, dense_init
 from repro.models.layers import apply_rope, decoded_of, dense_of, rope
 
@@ -204,8 +208,8 @@ def attn_apply(
         vf = jnp.repeat(v, rep, axis=2)
         q, kf, vf = _shard_qkv(q, kf, vf, heads_div)
         with jax.named_scope("core"):
-            out = flash_attention(q, kf, vf, window=window,
-                                  softcap=cfg.attn_logit_softcap)
+            out = dispatch.flash_attend(q, kf, vf, window=window,
+                                        softcap=cfg.attn_logit_softcap)
         new_cache = None
     elif is_paged_cache(cache):
         assert window is None, "paged KV pools do not serve ring buffers"
@@ -327,7 +331,6 @@ def _paged_attend(q, k_new, v_new, cache, cfg: ArchConfig, *,
     out-of-range writes from recycled rows and the gathered-but-invalid
     tail are harmless (the tail is masked out before the softmax anyway).
     """
-    from repro.kernels import dispatch
     B, S, h, hd = q.shape
     pool_k = cache["kp"]
     page = pool_k.shape[2]
@@ -537,8 +540,8 @@ def mla_apply(
         heads_div = h % model_axis_size() == 0
         qq, k, v = _shard_qkv(qq, k, v, heads_div)
         with jax.named_scope("core"):
-            out = flash_attention(qq, k, v,
-                                  scale=1.0 / math.sqrt(nope + rpe))
+            out = dispatch.flash_attend(qq, k, v,
+                                        scale=1.0 / math.sqrt(nope + rpe))
         new_cache = None
     else:
         # absorbed decode folds kv_up into q/ctx einsums: dense view needed

@@ -126,6 +126,44 @@ def test_paged_attend_lns_pool_compiles(one_chip, seq):
     assert "tpu_custom_call" in hlo
 
 
+def _attend_grads(q, k, v):
+    """Forward plus backward of the dispatched training attention."""
+    from repro.kernels import dispatch
+
+    def loss(q, k, v):
+        out = dispatch.flash_attend(q, k, v, backend="pallas",
+                                    interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def test_flash_attend_fwd_bwd_compiles(one_chip):
+    """The train cell's attention core (batch 16 x 2048, 9 heads of 64):
+    the forward, dK/dV and dQ kernels all survive as Mosaic calls."""
+    qkv = ((16, 2048, CFG.num_heads, CFG.head_dim), jnp.bfloat16)
+    hlo = _compile(_attend_grads, qkv, qkv, qkv, sharding=one_chip)
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("heads,kernel", [(8, True), (9, False)],
+                         ids=["heads-sharded", "sequence-parallel"])
+def test_flash_attend_on_four_chip_mesh(topo, heads, kernel):
+    """Under a (1, 4) mesh the kernel runs per head shard; a head count
+    the model axis does not divide keeps the jnp oracle."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.distributed.sharding import shard_ctx
+
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    x = jax.ShapeDtypeStruct((2, 512, heads, CFG.head_dim), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P()))
+    with shard_ctx(mesh):
+        hlo = jax.jit(_attend_grads).lower(x, x, x).compile().as_text()
+    assert ("tpu_custom_call" in hlo) is kernel
+
+
 def test_dispatched_kernels_compile_on_four_chip_mesh(topo):
     """XLA cannot partition a Mosaic kernel: under a (1, 4) mesh the
     dispatch layer must hand each kernel to shard_map (the routed GEMM

@@ -47,21 +47,21 @@ def half_batch_builder(cfg, qcfg, mcfg):
     return half
 
 
-def calibrate_train(cell, seeds, variants, emit):
+def calibrate_train(cell, seeds, variants, emit, mesh):
     from bench.harness import train
     jobs = {}
     for v in variants:
         if v == "control":
-            jobs[v] = train.TrainJob(cell, update_bits=8)
+            jobs[v] = train.TrainJob(cell, update_bits=8, mesh=mesh)
         elif v == "half_batch":
             saved = train.build_step
             train.build_step = half_batch_builder
             try:
-                jobs[v] = train.TrainJob(cell)
+                jobs[v] = train.TrainJob(cell, mesh=mesh)
             finally:
                 train.build_step = saved
         else:
-            jobs[v] = train.TrainJob(cell)
+            jobs[v] = train.TrainJob(cell, mesh=mesh)
     for seed in seeds:
         ref = None
         for v, job in jobs.items():
@@ -79,12 +79,12 @@ def calibrate_train(cell, seeds, variants, emit):
                   "program": run.readings, "reference": ref})
 
 
-def calibrate_serve(cell, seeds, variants, seconds, emit):
+def calibrate_serve(cell, seeds, variants, seconds, emit, mesh):
     from bench.harness import serve
     engine = None
     for seed in seeds:
         t0 = time.perf_counter()
-        run = serve.ServeRun(cell, seed)
+        run = serve.ServeRun(cell, seed, mesh=mesh)
         run.setup(engine)
         engine = run.engine
         run.window(seconds)
@@ -122,7 +122,7 @@ def main() -> int:
     from bench.harness import runner, spec
     cell = spec.load_cell(ROOT, args.workload)
     runner.prepare_environment(cell)
-    runner.require_chips(cell.chips)
+    mesh = cell.make_mesh(runner.require_chips(cell.chips))
     out = Path(args.out)
     out.mkdir(parents=True, exist_ok=True)
     path = out / f"{cell.name}.jsonl"
@@ -137,9 +137,9 @@ def main() -> int:
         print(json.dumps(brief, default=float), flush=True)
 
     if cell.traffic["kind"] == "train":
-        calibrate_train(cell, seeds, variants, emit)
+        calibrate_train(cell, seeds, variants, emit, mesh)
     else:
-        calibrate_serve(cell, seeds, variants, args.seconds, emit)
+        calibrate_serve(cell, seeds, variants, args.seconds, emit, mesh)
     return 0
 
 

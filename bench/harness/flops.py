@@ -1,15 +1,16 @@
 """Operations and bytes computed from shapes, and the chip's peaks.
 
-Model FLOPs count every matmul parameter the forward pass uses (the tied
-head included, the embedding lookup not) and attention's score and value
-products at full width; recomputation is not counted. Kernel counts are
-per call, from the call's own operand shapes.
+Model FLOPs are the architecture module's (``reference.py`` states the
+interface): active parameters only, recomputation not counted. Kernel
+counts are per call, from the call's own operand shapes.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
 from typing import Dict
+
+from bench.harness import spec
 
 PEAKS_FILE = Path(__file__).with_name("peaks.json")
 
@@ -24,29 +25,17 @@ def peaks(device_kind: str) -> Dict[str, float]:
     return table[device_kind]
 
 
-def matmul_params(dims) -> int:
-    """N: parameters of every matmul the forward pass runs."""
-    d, q, k = dims.d, dims.heads * dims.hd, dims.kv * dims.hd
-    per_layer = d * q + 2 * d * k + q * d + 3 * d * dims.ff
-    return dims.layers * per_layer + dims.vocab * d
-
-
-def attn_width(dims) -> int:
-    return dims.heads * dims.hd
-
-
 def train_flops_per_token(dims, seq: int) -> float:
-    """6 N + 12 L d_attn s."""
-    return 6.0 * matmul_params(dims) + 12.0 * dims.layers * attn_width(
-        dims) * seq
+    """Model FLOPs of one training token at sequence length ``seq``, as
+    the architecture module that made ``dims`` counts them."""
+    return spec.module_of(dims).train_flops_per_token(dims, seq)
 
 
 def decode_flops(dims, contexts) -> float:
-    """One decode step of rows at the given context lengths (the new
-    token included): 2 N + 4 L d_attn c per row."""
-    n2 = 2.0 * matmul_params(dims)
-    la = 4.0 * dims.layers * attn_width(dims)
-    return sum(n2 + la * c for c in contexts)
+    """Model FLOPs of one decode step of rows at the given context lengths
+    (the new token included), each row as the dims' module counts it."""
+    row = spec.module_of(dims).decode_row_flops
+    return sum(row(dims, c) for c in contexts)
 
 
 def qmatmul_work(m: int, k: int, n: int, a_bytes: int = 1, b_bytes: int = 1,

@@ -17,6 +17,26 @@ It imports nothing of the program and takes nothing the program made: it
 remakes the weights from the seed with ``lnsgen`` and the tokens with
 ``data``. Layer and leaf names follow the program's parameter tree, so the
 two sides' per-leaf readings can be compared by name.
+
+This is also the dense architecture module, the one a configuration gets
+when it names no ``reference`` (``bench/harness/spec.py``). An architecture
+module is everything architecture-specific the harness reads, and exports:
+
+* ``MODEL_KEYS``: Hugging Face ``config.json`` key -> ``ArchConfig`` field,
+  applied over the program's registry entry;
+* ``NOT_APPLIED``: keys it records but does not apply, each with its reason
+  (any other key of the file's ``model`` is refused by name);
+* ``Dims``, hashable, with ``Dims.from_model(model)``, which raises
+  ``ValueError`` naming a key whose value the module does not compute;
+* ``train_flops_per_token(dims, seq)`` and ``decode_row_flops(dims, c)``:
+  model FLOPs of one training token at sequence length ``seq`` and of one
+  decode row at context ``c``, counting active parameters only (the
+  experts a token runs and the shared ones, never every expert);
+* ``TrainSpec``, ``train_readings(words, batch_fn, dims, spec, steps)`` and
+  ``serve_logits(words, tokens, n_valid, dims, store, fwd)``.
+
+This module computes no experts, latent attention, sliding window, QKV
+bias or untied head: a configuration with any of them names its own.
 """
 from __future__ import annotations
 
@@ -41,6 +61,37 @@ EMBED = "embed/tok"
 FINAL = "final_norm"
 
 
+# Hugging Face config.json key -> the program's ArchConfig field
+MODEL_KEYS = {
+    "num_hidden_layers": "num_layers",
+    "hidden_size": "d_model",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim",
+    "intermediate_size": "d_ff",
+    "vocab_size": "vocab_size",
+    "tie_word_embeddings": "tie_embeddings",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "norm_eps",
+    "hidden_act": "act_fn",
+    "attention_bias": "qkv_bias",
+}
+
+NOT_APPLIED = {
+    "max_position_embeddings": "rotary positions are computed, not "
+                               "tabled; the traffic keeps sequences within "
+                               "it",
+}
+
+# (key, the one value computed here, the value a file without the key
+# means, what it computes): a file states its head, since families differ
+COMPUTED = (
+    ("tie_word_embeddings", True, None, "a tied head"),
+    ("attention_bias", False, False, "projections without bias"),
+    ("hidden_act", "silu", "silu", "the gated SiLU MLP"),
+)
+
+
 @dataclasses.dataclass(frozen=True)
 class Dims:
     layers: int
@@ -55,6 +106,11 @@ class Dims:
 
     @classmethod
     def from_model(cls, m: Dict) -> "Dims":
+        for key, computed, absent, what in COMPUTED:
+            if m.get(key, absent) != computed:
+                raise ValueError(f"model key {key} = {m.get(key, absent)!r}"
+                                 f": this module computes {what} only; name "
+                                 f"an architecture module with 'reference'")
         return cls(layers=m["num_hidden_layers"], d=m["hidden_size"],
                    heads=m["num_attention_heads"],
                    kv=m["num_key_value_heads"], hd=m["head_dim"],
@@ -66,6 +122,32 @@ class Dims:
         return {"attn/wq": (d, q), "attn/wk": (d, k), "attn/wv": (d, k),
                 "attn/wo": (q, d), "mlp/up": (d, f), "mlp/gate": (d, f),
                 "mlp/down": (f, d)}[name]
+
+
+def matmul_params(dims: Dims) -> int:
+    """N: parameters of every matmul the forward pass runs (the tied head
+    included, the embedding lookup not)."""
+    d, q, k = dims.d, dims.heads * dims.hd, dims.kv * dims.hd
+    per_layer = d * q + 2 * d * k + q * d + 3 * d * dims.ff
+    return dims.layers * per_layer + dims.vocab * d
+
+
+def attn_width(dims: Dims) -> int:
+    return dims.heads * dims.hd
+
+
+def train_flops_per_token(dims: Dims, seq: int) -> float:
+    """6 N + 12 L d_attn s: attention's score and value products at full
+    width; recomputation is not counted."""
+    return 6.0 * matmul_params(dims) + 12.0 * dims.layers * attn_width(
+        dims) * seq
+
+
+def decode_row_flops(dims: Dims, context: int) -> float:
+    """2 N + 4 L d_attn c for a row at context ``c`` (the new token
+    included)."""
+    return 2.0 * matmul_params(dims) + 4.0 * dims.layers * attn_width(
+        dims) * context
 
 
 def leaf_name(name: str) -> str:

@@ -161,9 +161,9 @@ class Tracer:
 
 
 def run_train(cell, args, root: Path, devs, t_start: float, trace: bool,
-              **job_kw) -> Dict:
-    from bench.harness import reference, train
-    run = train.TrainRun(cell, args.seed, **job_kw)
+              mesh=None, **job_kw) -> Dict:
+    from bench.harness import train
+    run = train.TrainRun(cell, args.seed, mesh=mesh, **job_kw)
     run.setup()
     programs = {}
     if trace:
@@ -186,7 +186,7 @@ def run_train(cell, args, root: Path, devs, t_start: float, trace: bool,
     red = tracer.reduce() if tracer else None
     ref = run.reference_readings()
     numbers = train.compare(run.readings, ref)
-    rec = {"kind": "train", "dims": reference.Dims.from_model(cell.model),
+    rec = {"kind": "train", "dims": run.job.dims,
            "trace": red, "programs": programs,
            "train": {"tokens": win["tokens"], "window_s": win["window_s"],
                      "seq": run.job.seq,
@@ -199,16 +199,16 @@ def run_train(cell, args, root: Path, devs, t_start: float, trace: bool,
                      "reference": ref}}
 
 
-def run_serve(cell, args, root: Path, devs, t_start: float, trace: bool
-              ) -> Dict:
-    from bench.harness import flops, reference, serve
-    run = serve.ServeRun(cell, args.seed)
+def run_serve(cell, args, root: Path, devs, t_start: float, trace: bool,
+              mesh=None) -> Dict:
+    from bench.harness import flops, serve
+    run = serve.ServeRun(cell, args.seed, mesh=mesh)
     run.setup()
     programs = {}
     setup_s = time.monotonic() - t_start
     tracer = Tracer(root, cell.name, TRACE_SECONDS) if trace else None
     traced_flops = [0.0]
-    dims = reference.Dims.from_model(cell.model)
+    dims = run.dims
     if tracer:
         # trace the window's last seconds: starting and stopping the
         # profiler stalls the host, so the host's own per-layer readings
@@ -306,7 +306,8 @@ def execute(cell, args, root: Path, t_start: float, **run_kw) -> Dict:
     trace = bool(args.trace)
     kind = cell.traffic["kind"]
     fn = run_train if kind == "train" else run_serve
-    out = fn(cell, args, root, devs, t_start, trace, **run_kw)
+    out = fn(cell, args, root, devs, t_start, trace,
+             mesh=cell.make_mesh(devs), **run_kw)
     limits = limits_of(root, cell.name)
     correct, checks = judge(out["numbers"], limits)
     info = device_info(devs)

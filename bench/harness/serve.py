@@ -18,14 +18,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.harness import lnsgen, reference, spec, traffic, weights
+from bench.harness import lnsgen, spec, traffic, weights
 
 TEMPERATURE = 0.8
 CHECK_TOKENS = 300          # served tokens the reference rechecks, at least
 
 
-def build_engine(cfg, params, opts: dict):
-    """The program's engine (the timed path)."""
+def build_engine(cfg, params, opts: dict, mesh=None):
+    """The program's engine (the timed path), on ``mesh`` where the cell
+    has one."""
     from repro.core.lns import LNSFormat
     from repro.core.quantizer import QuantConfig
     from repro.optim.madam import MadamConfig
@@ -34,7 +35,7 @@ def build_engine(cfg, params, opts: dict):
     qcfg = QuantConfig.lns_madam(bits=q["bits"], gamma=q["gamma"])
     mcfg = MadamConfig(update_format=LNSFormat(bits=q["bits"],
                                                gamma=q["gamma"]))
-    return Engine(cfg, qcfg, mcfg, params, **opts["engine"])
+    return Engine(cfg, qcfg, mcfg, params, mesh=mesh, **opts["engine"])
 
 
 def needed_buckets(engine, mix: dict) -> List[int]:
@@ -53,13 +54,14 @@ def needed_buckets(engine, mix: dict) -> List[int]:
 class ServeRun:
     """Set-up, window and check of one serving cell run."""
 
-    def __init__(self, cell: spec.Cell, seed: int):
+    def __init__(self, cell: spec.Cell, seed: int, mesh=None):
         self.cell = cell
         self.seed = seed
+        self.mesh = mesh
         self.words = lnsgen.seed_words(seed)
         self.cfg = spec.arch_config(cell.config)
         self.opts = cell.config["serve"]
-        self.dims = reference.Dims.from_model(cell.model)
+        self.dims = cell.dims
         self.engine = None
         self.states: Dict[int, object] = {}
         self.plan: List[traffic.Planned] = []
@@ -70,10 +72,21 @@ class ServeRun:
     # -- set-up ------------------------------------------------------------
 
     def make_params(self):
+        """The packed weights; on a mesh, made straight into the shardings
+        the engine serves them in."""
         q = self.opts["quant"]
-        params = jax.jit(lambda w: weights.make_params(
-            w, self.cfg, q["bits"], q["gamma"]))(self.words)
-        return jax.block_until_ready(params)
+
+        def make(w):
+            return weights.make_params(w, self.cfg, q["bits"], q["gamma"])
+        if self.mesh is None:
+            return jax.block_until_ready(jax.jit(make)(self.words))
+        from repro.distributed.params_sharding import params_shardings
+        from repro.distributed.sharding import serving_rules
+        out = params_shardings(jax.eval_shape(make, self.words), self.mesh,
+                               serving_rules(self.cfg, self.mesh),
+                               serving=True)
+        return jax.block_until_ready(
+            jax.jit(make, out_shardings=out)(self.words))
 
     def setup(self, engine=None) -> None:
         """Weights from the seed, then the engine and its warm-up; an
@@ -85,7 +98,8 @@ class ServeRun:
             engine.reset()
             self.engine = engine
             return
-        self.engine = build_engine(self.cfg, self.make_params(), self.opts)
+        self.engine = build_engine(self.cfg, self.make_params(), self.opts,
+                                   mesh=self.mesh)
         self.warm_up()
 
     def _request(self, p: traffic.Planned, arrival: float):
@@ -309,13 +323,15 @@ class _GcClock:
             gc.callbacks.remove(self._cb)
 
 
-def served_gaps(words, sample: List[dict], dims: reference.Dims,
-                store: tuple, fwd: tuple, control: Optional[tuple] = None,
+def served_gaps(words, sample: List[dict], dims, store: tuple, fwd: tuple,
+                control: Optional[tuple] = None,
                 bucket: int = 256) -> List[float]:
     """Per request, the widest gap by which a served token's reference
-    logit lies below the reference's best at its position. With
+    logit (the dims' module's ``serve_logits``) lies below the
+    reference's best at its position. With
     ``control`` (a lower-precision grid), the gap of the token the control
     puts first instead."""
+    serve_logits = spec.module_of(dims).serve_logits
     gaps = []
     w = jnp.asarray(words)
     for req in sample:
@@ -323,14 +339,14 @@ def served_gaps(words, sample: List[dict], dims: reference.Dims,
         n = len(seq)
         padded = -(-n // bucket) * bucket
         toks = jnp.asarray(seq + [0] * (padded - n), jnp.int32)
-        ref = reference.serve_logits(w, toks, n, dims, store, fwd)
+        ref = serve_logits(w, toks, n, dims, store, fwd)
         p0 = len(req["prompt"]) - 1
         rows = ref[p0:n]
         best = jnp.max(rows, axis=-1)
         if control is None:
             served = jnp.asarray(req["served"], jnp.int32)
         else:
-            ctl = reference.serve_logits(w, toks, n, dims, store, control)
+            ctl = serve_logits(w, toks, n, dims, store, control)
             served = jnp.argmax(ctl[p0:n], axis=-1)
         got = jnp.take_along_axis(rows, served[:, None], axis=-1)[:, 0]
         gaps.append(float(jnp.max(best - got)))

@@ -17,7 +17,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from bench.harness import data, lnsgen, reference, spec, weights
+from bench.harness import data, lnsgen, spec, weights
 
 CHECK_STEPS = 3
 
@@ -29,11 +29,15 @@ def build_step(cfg, qcfg, mcfg):
 
 
 class TrainJob:
-    """The cell's program objects: config, step, state maker and feed."""
+    """The cell's program objects: config, step, state maker and feed.
+    With a ``mesh`` the state and each batch are made straight into the
+    program's shardings under the training rules; without one the step
+    runs on a one-device mesh and the state is made on that device."""
 
     def __init__(self, cell: spec.Cell, batch: Optional[int] = None,
                  update_bits: Optional[int] = None, mesh=None):
         from repro.core.quantizer import QuantConfig
+        from repro.distributed.params_sharding import batch_shardings
         from repro.distributed.sharding import shard_ctx
         from repro.launch.mesh import make_host_mesh
         from repro.configs import get_rules
@@ -51,7 +55,7 @@ class TrainJob:
         self.batch = batch or cell.traffic["batch"]
         self.seq = cell.traffic["seq"]
         self.noise = cell.traffic.get("noise_levels", 16)
-        self.dims = reference.Dims.from_model(cell.model)
+        self.dims = cell.dims
         self.mesh = make_host_mesh(1, 1) if mesh is None else mesh
         self.rules = get_rules(cell.config["arch"])
         step = build_step(self.cfg, self.qcfg, self.mcfg)
@@ -60,9 +64,23 @@ class TrainJob:
             with shard_ctx(self.mesh, self.rules):
                 return step(state, batch)
 
-        self.step_fn = jax.jit(train_step, donate_argnums=(0,))
-        self.state_fn = jax.jit(self.make_state)
-        self.batch_fn = jax.jit(self.make_batch)
+        if mesh is None:
+            self.step_fn = jax.jit(train_step, donate_argnums=(0,))
+            self.state_fn = jax.jit(self.make_state)
+            self.batch_fn = jax.jit(self.make_batch)
+            return
+        words = jax.ShapeDtypeStruct((2,), jnp.uint32)
+        state_sh = weights.train_state_shardings(
+            jax.eval_shape(self.make_state, words), mesh, self.rules)
+        batch = jax.eval_shape(self.make_batch, words,
+                               jax.ShapeDtypeStruct((), jnp.int32))
+        # the step hands the state back in the shardings it was made in
+        # (not merely equivalent ones), so one compiled step serves all
+        self.step_fn = jax.jit(train_step, donate_argnums=(0,),
+                               out_shardings=(state_sh, None))
+        self.state_fn = jax.jit(self.make_state, out_shardings=state_sh)
+        self.batch_fn = jax.jit(self.make_batch, out_shardings=(
+            batch_shardings(batch, mesh, self.rules)))
 
     @property
     def tokens_per_step(self) -> int:
@@ -76,16 +94,16 @@ class TrainJob:
                                vocab=self.cfg.vocab_size, noise=self.noise)
 
 
-
-def ref_spec(cell: spec.Cell) -> reference.TrainSpec:
-    """The reference's numerics, read from the configuration file."""
+def ref_spec(cell: spec.Cell):
+    """The reference's numerics (its module's ``TrainSpec``), read from
+    the configuration file."""
     opts = cell.config["train"]
     q, m = opts["quant"], opts["madam"]
     ubits = q["update_bits"]
     # the update grid keeps the forward format's range: gamma scales with
     # the added bits (paper section 6.1.1)
     ugamma = q["gamma"] << max(ubits - q["bits"], 0)
-    return reference.TrainSpec(fwd=(q["bits"], q["gamma"]),
+    return cell.arch.TrainSpec(fwd=(q["bits"], q["gamma"]),
                                upd=(ubits, ugamma), lr=m["lr"],
                                beta=m["beta"])
 
@@ -224,6 +242,6 @@ class TrainRun:
         batch_fn = jax.jit(
             lambda i: data.make_batch(words, i, batch=job.batch, seq=job.seq,
                                       vocab=job.dims.vocab, noise=job.noise))
-        return reference.train_readings(
+        return self.cell.arch.train_readings(
             words, lambda i: batch_fn(jnp.int32(i)), job.dims,
             ref_spec(self.cell), steps=CHECK_STEPS)

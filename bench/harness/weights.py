@@ -4,7 +4,9 @@ The tree's structure and shapes come from the program's own initializer,
 read abstractly (``jax.eval_shape``: no values); every value comes from
 ``lnsgen`` under the benchmark's seed. Matrices (>= 2-D per layer) become
 packed ``LNSWeight`` leaves, norm gains stay float32, as the program keeps
-them. One jitted call makes the whole tree on the device.
+them. One jitted call makes the whole tree on the device; on a mesh it
+makes each leaf straight into the program's sharding of it (the shardings
+below), so no device holds more of the tree than its rules give it.
 """
 from __future__ import annotations
 
@@ -73,3 +75,18 @@ def make_train_state(words: jax.Array, cfg, mcfg):
     init_opt, _ = madam_lns(mcfg)
     return TrainState(params=params, opt=init_opt(params),
                       step=jnp.zeros((), jnp.int32))
+
+
+def train_state_shardings(state_shape, mesh, rules):
+    """The program's sharding of a ``TrainState``: parameters and their
+    second moments by the training rules, the step count replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.distributed.params_sharding import (opt_logical_axes,
+                                                   params_shardings,
+                                                   tree_shardings)
+    p, opt = state_shape.params, state_shape.opt
+    return type(state_shape)(
+        params=params_shardings(p, mesh, rules),
+        opt=tree_shardings(opt_logical_axes(p, opt), mesh, rules),
+        step=NamedSharding(mesh, PartitionSpec()))

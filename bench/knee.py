@@ -43,11 +43,11 @@ def main() -> int:
     from bench.harness import runner, serve, spec
     cell = spec.load_cell(ROOT, args.workload)
     runner.prepare_environment(cell)
-    runner.require_chips(cell.chips)
+    mesh = cell.make_mesh(runner.require_chips(cell.chips))
     engine = None
     for rate in (float(r) for r in args.rates.split(",")):
         cell.traffic = dict(cell.traffic, rate=rate)
-        run = serve.ServeRun(cell, args.seed)
+        run = serve.ServeRun(cell, args.seed, mesh=mesh)
         run.setup(engine)
         engine = run.engine
         mid = {}

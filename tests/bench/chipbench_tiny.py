@@ -30,27 +30,32 @@ TRAIN_CELL = "smollm-135m.train.seq2048"
 SERVE_CELL = "smollm-135m.serve.short"
 
 
-def tiny_cell(workload: str):
+def tiny_cell(workload: str, chips: int = 1, **config):
     """The workload of BENCHMARK.json with its configuration cut to a tiny
-    model and its traffic to a tiny mix (every other setting as is)."""
+    model and its traffic to a tiny mix (every other setting as is);
+    ``chips`` and the configuration keys in ``config`` (``reference``,
+    ``mesh``) replace the cell's."""
     from bench.harness import spec
     cell = spec.load_cell(ROOT, workload)
     cell.config = json.loads(json.dumps(cell.config))
     cell.config["model"] = dict(TINY_MODEL)
     if "serve" in cell.config:
         cell.config["serve"]["engine"] = dict(TINY_ENGINE)
+    cell.config.update(config)
+    cell.chips = chips
     cell.traffic = dict(TINY_TRAFFIC[cell.traffic["kind"]])
     return cell
 
 
 def run_tiny(workload: str, seed: int = 2 ** 33 + 17, seconds: float = 1.5,
-             trace: int = 0, **run_kw):
-    """One run of the harness on the CPU, its look for a chip skipped and
-    the v5e's peaks taken for the CPU's; returns the result object."""
+             trace: int = 0, cell=None, **run_kw):
+    """One run of the harness on the CPU (of ``cell``, by default the tiny
+    cell of ``workload``), its look for a chip skipped and the v5e's peaks
+    taken for the CPU's; returns the result object."""
     import jax
 
     from bench.harness import flops, runner
-    cell = tiny_cell(workload)
+    cell = cell or tiny_cell(workload)
     args = argparse.Namespace(workload=workload, seed=seed, seconds=seconds,
                               trace=trace)
     v5e = flops.peaks("TPU v5 lite")

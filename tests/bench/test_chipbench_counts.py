@@ -13,19 +13,21 @@ ROOT = Path(__file__).resolve().parents[2]
 
 @pytest.fixture(scope="module")
 def smollm():
-    from bench.harness import reference
-    cfg = json.loads((ROOT / "bench/configs/smollm-135m.json").read_text())
-    return reference.Dims.from_model(cfg["model"])
+    """smollm-135m's dims, as the module its configuration resolves to
+    makes them."""
+    from bench.harness import spec
+    return spec.load_cell(ROOT, "smollm-135m.train.seq2048").dims
 
 
 def test_matmul_params_at_smollm_widths(smollm):
-    from bench.harness import flops
+    from bench.harness import spec
+    arch = spec.module_of(smollm)
     # per layer: q 576x576, k and v 576x192, o 576x576, up/gate/down
     # 576x1536 each; the tied head 576x49152
     layer = 576 * 576 + 2 * 576 * 192 + 576 * 576 + 3 * 576 * 1536
     assert layer == 3_538_944
-    assert flops.matmul_params(smollm) == 30 * layer + 49152 * 576
-    assert flops.matmul_params(smollm) == 134_479_872
+    assert arch.matmul_params(smollm) == 30 * layer + 49152 * 576
+    assert arch.matmul_params(smollm) == 134_479_872
 
 
 def test_train_and_decode_flops(smollm):
@@ -36,6 +38,11 @@ def test_train_and_decode_flops(smollm):
     assert flops.decode_flops(smollm, [100, 300]) == \
         2 * (2 * n) + 4 * 30 * 576 * 400
     assert flops.decode_flops(smollm, []) == 0
+    # the pinned numbers: 1,231,552,512 per training token at 2048, and
+    # 268,959,744 + 69,120 c per decode row
+    assert flops.train_flops_per_token(smollm, 2048) == 1_231_552_512
+    assert flops.decode_flops(smollm, [0]) == 268_959_744
+    assert flops.decode_flops(smollm, [1000]) == 268_959_744 + 69_120_000
 
 
 def test_kernel_work():

@@ -7,8 +7,8 @@ def test_token_altered_where_produced_fails(monkeypatch):
     from bench.harness import serve
     build = serve.build_engine
 
-    def broken(cfg, params, opts):
-        eng = build(cfg, params, opts)
+    def broken(cfg, params, opts, mesh=None):
+        eng = build(cfg, params, opts, mesh=mesh)
         sample = eng._sample_impl
 
         def off_by_one(logits, samp, step_offset=None):
